@@ -201,6 +201,7 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
     from repro.core.split import ComposedBoundaryStage, FusedBoundaryStage, \
         make_boundary_stage
     from repro.roofline.analysis import fused_boundary_terms
+    from repro.roofline.hw import TPU_V5E      # analytic v5e prediction
     bsz, feat = 16, 6272          # one conv0 crossing of the smoke model
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (bsz, feat), jnp.float32)
@@ -235,7 +236,8 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
         "max_abs_err": err,
         # fma re-association under jit puts the two paths ~1 ulp apart
         "fused_matches": bool(err <= 1e-5),
-        "roofline": fused_boundary_terms(bsz, feat, codec="int8")}
+        "roofline": fused_boundary_terms(bsz, feat, hw=TPU_V5E,
+                                         codec="int8")}
 
     # 5. scale: rounds-per-second vs population + sharded dispatch ---------
     from repro.fed.roster import Roster
@@ -351,12 +353,13 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
     from repro.fed.programs import fedavg_stacked, stack_trees
     from repro.fed.transport import make_codec
     from repro.roofline.analysis import agg_fuse_terms
+    from repro.roofline.hw import TPU_V5E
     leaf = (1 << 14) if fast else (1 << 17)
     template = {"w": jnp.zeros((leaf,), jnp.float32),
                 "b": jnp.zeros((leaf // 4,), jnp.float32)}
     n_total = sum(l.size for l in jax.tree.leaves(template))
     results["agg"] = {"codec": "int8", "elems": int(n_total),
-                      "roofline_c64": agg_fuse_terms(64, n_total,
+                      "roofline_c64": agg_fuse_terms(64, n_total, hw=TPU_V5E,
                                                      codec="int8")}
 
     def _best_us(fn, reps_):

@@ -35,6 +35,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.cache import use_compile_cache
+
 
 def main() -> None:
     fast = os.environ.get("BENCH_FAST", "0") == "1"
@@ -72,4 +74,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -28,6 +28,7 @@ Run: PYTHONPATH=src python examples/adaptive_control_demo.py
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
+from repro.launch.cache import use_compile_cache
 from repro.obs import load_run, replay_run
 
 CLIENTS = 2
@@ -111,4 +112,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -16,6 +16,7 @@ from repro.config import DCGANConfig
 from repro.core.devices import Client, Device
 from repro.core.selection import STRATEGIES, make_plan
 from repro.core.simulate import plan_epoch_time
+from repro.launch.cache import use_compile_cache
 from repro.models.dcgan import disc_layer_costs, disc_layer_names
 
 
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

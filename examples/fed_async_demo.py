@@ -23,6 +23,7 @@ import argparse
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
+from repro.launch.cache import use_compile_cache
 
 SCENARIOS = {
     "sync": {},
@@ -70,4 +71,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

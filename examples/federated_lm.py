@@ -20,6 +20,7 @@ import numpy as np
 from repro.config import reduce_for_smoke
 from repro.configs.registry import get_config
 from repro.data import synthetic_lm_batch
+from repro.launch.cache import use_compile_cache
 from repro.models.transformer import lm_init
 from repro.optim import make_optimizer
 from repro.runtime import make_fsl_train_step
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
+from repro.launch.cache import use_compile_cache
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "gan")
 
@@ -68,4 +69,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

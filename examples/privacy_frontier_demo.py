@@ -26,6 +26,7 @@ from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer, d_loss_fn
 from repro.data import partition_dirichlet, synthetic_mnist
 from repro.kernels.dp_clip.ops import dp_clip_noise_tree
+from repro.launch.cache import use_compile_cache
 from repro.privacy import (ActivationInversionAttack, best_match_psnr,
                            distance_correlation, invert_gradients,
                            make_prefix_fn, membership_inference,
@@ -113,4 +114,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

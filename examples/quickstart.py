@@ -14,6 +14,7 @@ from repro.config import DCGANConfig, reduce_for_smoke
 from repro.configs.registry import get_config
 from repro.core import FSLGANTrainer, make_pool, strategy_sweep
 from repro.data import partition_dirichlet, synthetic_lm_batch, synthetic_mnist
+from repro.launch.cache import use_compile_cache
 from repro.models.dcgan import disc_layer_costs, disc_layer_names
 from repro.models.transformer import lm_init
 from repro.optim import make_optimizer
@@ -66,6 +67,7 @@ def demo_lm_substrate():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     demo_split_planning()
     demo_fsl_gan()
     demo_lm_substrate()

@@ -10,6 +10,7 @@ import numpy as np
 from repro.config import reduce_for_smoke
 from repro.configs.registry import get_config
 from repro.data import synthetic_tokens
+from repro.launch.cache import use_compile_cache
 from repro.launch.serve import Request, serve_batch
 
 
@@ -30,4 +31,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

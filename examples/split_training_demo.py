@@ -29,6 +29,7 @@ from repro.core.gan import FSLGANTrainer
 from repro.core.split import partition_params
 from repro.data import partition_dirichlet, synthetic_mnist
 from repro.fed.transport import tree_bytes
+from repro.launch.cache import use_compile_cache
 from repro.privacy import (ActivationInversionAttack, best_match_psnr,
                            distance_correlation, make_shipped_prefix_fn)
 
@@ -138,4 +139,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -22,6 +22,7 @@ from collections import Counter
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
+from repro.launch.cache import use_compile_cache
 from repro.obs import validate_chrome_trace
 
 CLIENTS = 2
@@ -95,4 +96,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -403,11 +403,10 @@ class FSLGANTrainer:
         self.engine = FederationEngine(
             self.cfg.fed, specs, weighted=self.cfg.fsl.weighted_average,
             uplink_stage=self._uplink_stage, cohort_of=self._cohort_of)
-        if getattr(self.cfg.fed, "server_reduce", "decode") == "batched":
-            # the batched compressed-domain reduce shards its per-leaf
-            # wire stacks over the same client mesh the vectorized
-            # backend trains on (None when fed.shard_clients is off)
-            self.engine.set_mesh(self._client_mesh())
+        # the kernel-backed server reduces shard their per-leaf client
+        # stacks over the same client mesh the vectorized backend trains
+        # on (None when fed.shard_clients is off)
+        self.engine.set_mesh(self._client_mesh())
         self._engine_batches = batches_per_client
         if self.recorder is not None:
             self._attach_recorder(by_id)

@@ -236,6 +236,6 @@ def batched_reduce(codec_name: str, encs: Sequence[EncTree],
             scales = jnp.ones((len(encs),), jnp.float32)
         out.append(dequant_reduce_flat(wires, scales, w,
                                        use_kernel=use_kernel,
-                                       interpret=interpret)
+                                       interpret=interpret, mesh=mesh)
                    .reshape(t.shape).astype(t.dtype))
     return jax.tree.unflatten(jax.tree.structure(template), out)

@@ -134,7 +134,7 @@ class FederationEngine:
         # under "stream"/"batched" the ARRIVE queue carries wire payloads
         # instead of decoded trees (O(1) live decoded state).
         self.server_reduce = str(getattr(fed_cfg, "server_reduce", "decode"))
-        # optional client mesh for the "batched" reduce: stacked wire
+        # optional client mesh for the kernel-backed reduces: stacked
         # leaves land on the `clients` axis before the fused reduce
         self.mesh = None
         # pre-codec uplink transform (privacy/defenses.DPUplinkStage):
@@ -203,11 +203,28 @@ class FederationEngine:
         self.deadline_s = float(deadline_s)
 
     def set_mesh(self, mesh) -> None:
-        """Attach a client device mesh for the "batched" server reduce:
-        per-leaf wire stacks are placed with
-        ``sharding.stacked_shardings`` before the fused dequant-reduce.
-        ``None`` (the default) keeps every reduce single-device."""
+        """Attach a client device mesh for the kernel-backed server
+        reduces ("decode" through the fedavg kernel, "batched"): per-leaf
+        client stacks are placed with ``sharding.stacked_shardings`` and
+        reduced per device (``sharding.specs.psum_client_rows``).
+        ``None`` (the default) keeps every reduce single-device.
+
+        A compiled Mosaic kernel cannot take arrays that span devices, and
+        the "stream" fold and the edge-cohort reduce have no per-device
+        form: on a multi-device mesh they are refused here rather than
+        left to fail at compile time."""
+        if (mesh is not None and mesh.size > 1
+                and self.cfg.kernel_aggregation
+                and not self.cfg.kernel_interpret
+                and (self.server_reduce == "stream"
+                     or self.hierarchy is not None)):
+            raise ValueError(
+                "fed.kernel_aggregation with server_reduce='stream' or "
+                "hierarchy_cohorts >= 2 cannot run its Pallas kernels on "
+                f"a {mesh.size}-device clients mesh; use server_reduce="
+                "'decode' or 'batched', or turn fed.shard_clients off")
         self.mesh = mesh
+        self.policy.mesh = mesh
 
     def set_tracer(self, tracer, *, batch_cap: int = 0) -> None:
         """Attach a :class:`repro.obs.Tracer`; subsequent rounds emit

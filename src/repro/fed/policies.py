@@ -56,6 +56,7 @@ def _mix(global_tree, update_tree, rate: float):
 class AggregationPolicy:
     """Base: buffer everything, do nothing until told."""
     name = "base"
+    mesh = None         # client device mesh (FederationEngine.set_mesh)
 
     def on_update(self, global_tree, up: ClientUpdate
                   ) -> Tuple[Any, bool]:
@@ -99,7 +100,8 @@ class SyncFedAvg(AggregationPolicy):
         self._buffer = []
         if self.use_kernel:
             from repro.kernels.fedavg.ops import fedavg_trees
-            return fedavg_trees(trees, weights, interpret=self.interpret)
+            return fedavg_trees(trees, weights, interpret=self.interpret,
+                                mesh=self.mesh)
         return _fedavg(trees, weights)
 
 

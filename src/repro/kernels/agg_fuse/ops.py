@@ -17,20 +17,28 @@ from repro.kernels.agg_fuse.kernel import (dequant_acc_kernel,
                                            scatter_acc_kernel)
 from repro.kernels.agg_fuse.ref import (dequant_acc_ref, dequant_reduce_ref,
                                         scatter_acc_ref)
+from repro.sharding.specs import psum_client_rows
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("use_kernel", "interpret", "mesh"))
 def dequant_reduce_flat(wires: jnp.ndarray, scales: jnp.ndarray,
                         weights: jnp.ndarray, *, use_kernel: bool = False,
-                        interpret: bool = False) -> jnp.ndarray:
+                        interpret: bool = False, mesh=None) -> jnp.ndarray:
     """Batch reduce: (C, N) wire rows + per-client (C,) scales and fedavg
     weights -> (N,) fp32 weighted MEAN of the dequantized rows (weights
-    normalized here, like ``fedavg_flat``)."""
+    normalized here, like ``fedavg_flat``).
+
+    ``mesh``: a mesh with a ``clients`` axis the rows are sharded over
+    (``sharding.specs.psum_client_rows``)."""
     w = (weights / jnp.sum(weights)).astype(jnp.float32)
     coefs = jnp.stack([w, scales.astype(jnp.float32)], axis=1)
-    if use_kernel:
-        return dequant_reduce_kernel(wires, coefs, interpret=interpret)
-    return dequant_reduce_ref(wires, coefs)
+    if not use_kernel:
+        return dequant_reduce_ref(wires, coefs)
+    kernel = functools.partial(dequant_reduce_kernel, interpret=interpret)
+    if mesh is None:
+        return kernel(wires, coefs)
+    return psum_client_rows(kernel, mesh, wires, coefs)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"),

@@ -8,7 +8,7 @@ them into one streaming pass over the flattened ``(B, N)`` tensor,
 patterned on the two-phase ``kernels/dp_clip`` grid:
 
   * ``int8`` only — phase 0 streams tiles accumulating the global
-    ``amax`` into a persistent (1, 1) VMEM scratch (the quantization
+    ``amax`` into a persistent SMEM scalar (the quantization
     scale needs the whole tensor, like the clip norm does);
   * phase P-2 streams tiles through the qdq and accumulates per-example
     partial squared norms into a (B, 1) VMEM scratch;
@@ -26,12 +26,15 @@ keep handling it.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.boundary_fuse.ref import CODECS, NORM_EPS
+from repro.kernels.f16 import round_to_f16
 
 
 def _make_fuse_kernel(codec: str, num_phases: int):
@@ -42,22 +45,22 @@ def _make_fuse_kernel(codec: str, num_phases: int):
         @pl.when(jnp.logical_and(phase == 0, j == 0))
         def _init():
             norm_scr[...] = jnp.zeros_like(norm_scr)
-            amax_scr[...] = jnp.zeros_like(amax_scr)
+            amax_scr[0] = jnp.float32(0.0)
 
         x = x_ref[...].astype(jnp.float32)               # (B, bn)
 
         if codec == "int8":
             @pl.when(phase == 0)
             def _amax():
-                amax_scr[0, 0] = jnp.maximum(amax_scr[0, 0],
-                                             jnp.max(jnp.abs(x)))
+                amax_scr[0] = jnp.maximum(amax_scr[0], jnp.max(jnp.abs(x)))
                 o_ref[...] = jnp.zeros_like(o_ref)       # placeholder flush
 
         def qdq():
             if codec == "fp16":
-                return x.astype(jnp.float16).astype(jnp.float32)
+                # Mosaic on v5e cannot pack f32 to f16 vectors
+                return round_to_f16(x)
             if codec == "int8":
-                amax = amax_scr[0, 0]
+                amax = amax_scr[0]
                 s = jnp.where(amax > 0, amax / 127.0, 1.0)
                 return jnp.clip(jnp.round(x / s), -127.0, 127.0) * s
             return x
@@ -81,19 +84,29 @@ def _make_fuse_kernel(codec: str, num_phases: int):
     return kernel
 
 
+# bytes of one fp32 (B, block_n) tile: x, noise and out are each double-
+# buffered, so six tiles plus the kernel's temporaries must fit the 16 MiB
+# of VMEM Mosaic scopes for a kernel on TPU v5e
+_TILE_BYTES = 1 << 20
+
+
 def boundary_fuse_kernel(x: jnp.ndarray, clip, noise_scale,
                          noise: jnp.ndarray, *, codec: str = "none",
-                         block_n: int = 2048,
+                         block_n: Optional[int] = None,
                          interpret: bool = False) -> jnp.ndarray:
     """x: (B, N) flattened boundary tensor; noise: (B, N).  -> (B, N) f32.
 
     Arbitrary N: zero-padded to a block_n multiple (padded lanes add 0
     to every norm and to the amax, and emit 0 * noise_scale) and sliced
     back.  ``clip``/``noise_scale`` ride in one (1, 2) scalar tile.
+    ``block_n`` defaults to the widest multiple of 128 lanes whose
+    (B, block_n) tile fits ``_TILE_BYTES``.
     """
     if codec not in CODECS:
         raise ValueError(f"unknown fusable codec {codec!r}")
     b, n = x.shape
+    if block_n is None:
+        block_n = max(128, _TILE_BYTES // (4 * b) // 128 * 128)
     block_n = min(block_n, max(n, 1))
     pad = (-n) % block_n
     if pad:
@@ -112,8 +125,9 @@ def boundary_fuse_kernel(x: jnp.ndarray, clip, noise_scale,
                   pl.BlockSpec((b, block_n), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((b, block_n), lambda i, j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, n_padded), jnp.float32),
+        # the amax is a scalar: Mosaic stores scalars to SMEM only
         scratch_shapes=[pltpu.VMEM((b, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)],
+                        pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
     )(x.astype(jnp.float32), scal, noise.astype(jnp.float32))
     return out[:, :n] if pad else out

@@ -33,6 +33,7 @@ from repro.configs.registry import (ASSIGNED_ARCHS, SHAPES, SkippedShape,
 from repro.launch.mesh import make_production_mesh, mesh_chips
 from repro.launch import specs as S
 from repro.roofline import analyze_compiled
+from repro.roofline.hw import TPU_V5E       # the production pod's chip
 from repro.runtime import make_decode_step, make_prefill_step, make_train_step
 from repro.sharding.specs import (make_activation_policy,
                                   set_activation_policy)
@@ -127,21 +128,20 @@ def run_pair(arch: str, shape: str, multi_pod: bool,
     model_flops = factor * n_active * tokens
     rep = analyze_compiled(
         compiled, arch=arch, shape=shape, mesh_name=mesh_name,
-        chips=mesh_chips(mesh), model_flops=model_flops)
+        chips=mesh_chips(mesh), model_flops=model_flops, hw=TPU_V5E)
     # loop-aware terms via probe differencing (cost_analysis counts while
     # bodies once — see roofline/probes.py)
     try:
         if not probes:
             raise RuntimeError("probes disabled (--no-probes)")
         from repro.roofline.probes import probe_costs
-        from repro.roofline.hw import TPU_V5E as hw
         pc = probe_costs(cfg, mesh)
         rep.hlo_flops = pc["flops"]["total"]
         rep.hlo_bytes = pc["bytes"]["total"]
         rep.collective_bytes = pc["coll"]["total"]
-        rep.compute_term_s = rep.hlo_flops / hw.peak_flops_bf16
-        rep.memory_term_s = rep.hlo_bytes / hw.hbm_bw
-        rep.collective_term_s = rep.collective_bytes / hw.ici_bw_per_link
+        rep.compute_term_s = rep.hlo_flops / TPU_V5E.peak_flops_bf16
+        rep.memory_term_s = rep.hlo_bytes / TPU_V5E.hbm_bw
+        rep.collective_term_s = rep.collective_bytes / TPU_V5E.ici_bw_per_link
         probe_terms = pc
     except Exception as e:  # probes are best-effort; keep raw numbers
         probe_terms = {"error": f"{type(e).__name__}: {e}"}

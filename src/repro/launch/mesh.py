@@ -7,7 +7,7 @@ never touches jax device state — the dry-run must set
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -15,9 +15,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # axis_types defaults to Auto on every jax version; the explicit kwarg
-    # only exists on jax >= 0.5, so it is deliberately omitted.
-    return jax.make_mesh(shape, axes)
+    # jax.make_mesh defaults to Explicit axes; the sharding rules
+    # (sharding/specs) place arrays with with_sharding_constraint, which
+    # only accepts Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
@@ -25,7 +26,8 @@ def make_host_mesh(model_parallel: int = 1) -> Mesh:
     smoke tests and examples."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_client_mesh(max_devices: int = 0) -> Mesh:

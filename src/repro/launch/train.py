@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.launch.cache import use_compile_cache
 from repro.launch.distributed import log_topology, maybe_initialize_distributed
 from repro.config import RunConfig, reduce_for_smoke
 from repro.configs.registry import get_config
@@ -107,4 +108,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

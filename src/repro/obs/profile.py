@@ -14,8 +14,12 @@ Each profile records the three costs a kernel pays:
     (the constant SplitEasy warns dominates short on-device runs);
   * ``run_s``   — best-of-N executed wall time (block_until_ready);
   * roofline terms — flops / bytes from ``compiled.cost_analysis()``
-    against the target HwSpec (``repro.roofline.analysis.kernel_terms``),
-    i.e. where the kernel sits on the compute/memory roof.
+    against the peaks of the device the profile ran on, looked up by its
+    ``device_kind`` (``repro.roofline.hw``).  The host CPU has no peaks:
+    there the terms are None ("not measured").
+
+Each kernel runs as the training config runs it: its ``use_kernel`` and
+``kernel_interpret`` flags, never a forced interpret mode.
 """
 from __future__ import annotations
 
@@ -27,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.roofline.analysis import kernel_terms
-from repro.roofline.hw import TPU_V5E, HwSpec
+from repro.roofline.hw import HwSpec, hw_for_kind
 
 
 @dataclass
@@ -37,18 +41,26 @@ class KernelProfile:
     compile_s: float
     run_s: float                  # best-of-N executed time
     runs: int
+    device_kind: str = ""
     flops: float = 0.0
     bytes_accessed: float = 0.0
-    compute_term_s: float = 0.0
-    memory_term_s: float = 0.0
+    compute_term_s: Optional[float] = None
+    memory_term_s: Optional[float] = None
     arithmetic_intensity: float = 0.0
 
     def to_dict(self) -> Dict[str, Any]:
         return asdict(self)
 
 
-def profile_jit(name: str, fn: Callable, *args, hw: HwSpec = TPU_V5E,
-                runs: int = 3) -> KernelProfile:
+def device_peaks() -> Optional[HwSpec]:
+    """Peaks of the device JAX runs on: None on the host CPU, an error
+    for an accelerator missing from ``roofline.hw.PEAKS``."""
+    dev = jax.devices()[0]
+    return None if dev.platform == "cpu" else hw_for_kind(dev.device_kind)
+
+
+def profile_jit(name: str, fn: Callable, *args, runs: int = 3
+                ) -> KernelProfile:
     """Lower + compile + time one callable on the given args.
 
     ``fn`` is traced fresh through ``jax.jit`` so the lower/compile split
@@ -66,9 +78,10 @@ def profile_jit(name: str, fn: Callable, *args, hw: HwSpec = TPU_V5E,
         out = compiled(*args)
         jax.block_until_ready(out)
         best = min(best, time.perf_counter() - r0)
-    terms = kernel_terms(compiled, hw)
+    terms = kernel_terms(compiled, device_peaks())
     return KernelProfile(name=name, lower_s=t1 - t0, compile_s=t2 - t1,
-                         run_s=best, runs=max(1, runs), **terms)
+                         run_s=best, runs=max(1, runs),
+                         device_kind=jax.devices()[0].device_kind, **terms)
 
 
 # ---------------------------------------------------------------------------
@@ -76,27 +89,30 @@ def profile_jit(name: str, fn: Callable, *args, hw: HwSpec = TPU_V5E,
 # ---------------------------------------------------------------------------
 
 def profile_fedavg(*, num_clients: int = 4, n: int = 8192,
-                   interpret: bool = True, hw: HwSpec = TPU_V5E,
+                   use_kernel: bool = False, interpret: bool = False,
                    runs: int = 3) -> KernelProfile:
-    """The fedavg aggregation kernel: (C, N) stacked client updates ->
-    weighted mean.  ``interpret=True`` runs the Pallas kernel in interpret
-    mode (the CPU-safe path CI uses)."""
+    """The fedavg aggregation: (C, N) stacked client updates -> weighted
+    mean, through the Pallas kernel or its jnp reference."""
     from repro.kernels.fedavg.ops import fedavg_flat
+    from repro.kernels.fedavg.ref import fedavg_ref
     key = jax.random.PRNGKey(0)
     stacked = jax.random.normal(key, (num_clients, n), jnp.float32)
     weights = jnp.ones((num_clients,), jnp.float32)
 
     def fn(s, w):
-        return fedavg_flat(s, w, interpret=interpret)
+        if use_kernel:
+            return fedavg_flat(s, w, interpret=interpret)
+        return fedavg_ref(s, w / jnp.sum(w))
 
-    return profile_jit(f"fedavg_c{num_clients}_n{n}", fn, stacked, weights,
-                       hw=hw, runs=runs)
+    kind = "kernel" if use_kernel else "ref"
+    return profile_jit(f"fedavg_{kind}_c{num_clients}_n{n}", fn, stacked,
+                       weights, runs=runs)
 
 
 def profile_dp_clip(*, batch: int = 8, n: int = 4096, clip: float = 1.0,
                     sigma: float = 1.0, use_kernel: bool = False,
-                    interpret: bool = True, hw: HwSpec = TPU_V5E,
-                    runs: int = 3) -> KernelProfile:
+                    interpret: bool = False, runs: int = 3
+                    ) -> KernelProfile:
     """The dp_clip privatization: per-example (B, N) grads -> clipped,
     noised sum (the DP-SGD inner release)."""
     from repro.kernels.dp_clip.ops import dp_clip_noise_flat
@@ -113,14 +129,14 @@ def profile_dp_clip(*, batch: int = 8, n: int = 4096, clip: float = 1.0,
 
     kind = "kernel" if use_kernel else "ref"
     return profile_jit(f"dp_clip_{kind}_b{batch}_n{n}", fn, stacked, noise,
-                       hw=hw, runs=runs)
+                       runs=runs)
 
 
 def profile_boundary_fuse(*, batch: int = 8, n: int = 4096,
                           codec: str = "int8", clip: float = 1.0,
                           sigma: float = 0.5, use_kernel: bool = False,
-                          interpret: bool = True, hw: HwSpec = TPU_V5E,
-                          runs: int = 3) -> KernelProfile:
+                          interpret: bool = False, runs: int = 3
+                          ) -> KernelProfile:
     """The fused boundary-crossing stage (kernels/boundary_fuse): codec
     qdq + per-example clip + Gaussian noise over one flattened (B, N)
     boundary tensor — what every hop of a composed ``codec+dp`` split
@@ -140,13 +156,13 @@ def profile_boundary_fuse(*, batch: int = 8, n: int = 4096,
 
     kind = "kernel" if use_kernel else "ref"
     return profile_jit(f"boundary_fuse_{codec}_{kind}_b{batch}_n{n}",
-                       fn, x, noise, hw=hw, runs=runs)
+                       fn, x, noise, runs=runs)
 
 
 def profile_agg_fuse(*, num_clients: int = 4, n: int = 8192,
                      codec: str = "int8", use_kernel: bool = False,
-                     interpret: bool = True, hw: HwSpec = TPU_V5E,
-                     runs: int = 3) -> KernelProfile:
+                     interpret: bool = False, runs: int = 3
+                     ) -> KernelProfile:
     """The fused dequant-reduce server aggregation (kernels/agg_fuse):
     (C, N) compressed client wires + per-client scales -> one fp32
     weighted mean without materializing decoded trees — what
@@ -172,41 +188,38 @@ def profile_agg_fuse(*, num_clients: int = 4, n: int = 8192,
 
     kind = "kernel" if use_kernel else "ref"
     return profile_jit(f"agg_fuse_{codec}_{kind}_c{num_clients}_n{n}",
-                       fn, wires, scales, weights, hw=hw, runs=runs)
+                       fn, wires, scales, weights, runs=runs)
 
 
-def profile_engine_kernels(cfg=None, *, hw: HwSpec = TPU_V5E,
-                           runs: int = 3) -> Dict[str, Dict[str, Any]]:
+def profile_engine_kernels(cfg, *, runs: int = 3
+                           ) -> Dict[str, Dict[str, Any]]:
     """Profile the kernels one engine round leans on, sized from ``cfg``
-    when given (aggregation width = number of clients; dp_clip on when the
-    privacy subsystem is; boundary_fuse when the split stage composes).
-    Returns ``{name: profile dict}`` — what the recorder writes to
-    ``profile.json``."""
-    num_clients = cfg.fsl.num_clients if cfg is not None else 4
-    profiles = [profile_fedavg(num_clients=max(2, num_clients),
-                               interpret=True, hw=hw, runs=runs)]
-    dp_on = cfg is None or cfg.privacy.enabled
-    if dp_on:
-        # interpret mode keeps the Pallas path CPU-safe regardless of the
-        # training config's kernel flags — this is a probe, not training
+    (aggregation width = number of clients; dp_clip on when the privacy
+    subsystem is; boundary_fuse when the split stage composes) and run
+    with ``cfg``'s kernel flags.  Returns ``{name: profile dict}`` — what
+    the recorder writes to ``profile.json``."""
+    fed, priv, split = cfg.fed, cfg.privacy, cfg.split
+    num_clients = max(2, cfg.fsl.num_clients)
+    profiles = [profile_fedavg(num_clients=num_clients,
+                               use_kernel=fed.kernel_aggregation,
+                               interpret=fed.kernel_interpret, runs=runs)]
+    if priv.enabled:
         profiles.append(profile_dp_clip(
-            use_kernel=bool(cfg and cfg.privacy.use_kernel),
-            interpret=True, hw=hw, runs=runs))
-    stage = cfg.split.boundary_stage if cfg is not None else "int8+dp"
+            use_kernel=priv.use_kernel, interpret=priv.kernel_interpret,
+            runs=runs))
+    stage = split.boundary_stage
     if "+" in stage:
         codec = stage.split("+")[0]
         if codec in ("fp16", "int8"):
             profiles.append(profile_boundary_fuse(
-                codec=codec,
-                use_kernel=bool(cfg and cfg.split.use_kernel),
-                interpret=True, hw=hw, runs=runs))
+                codec=codec, use_kernel=split.use_kernel,
+                interpret=split.kernel_interpret, runs=runs))
     # compressed-domain server reduce (kernels/agg_fuse): profiled when a
     # dense lossy uplink codec is configured — the fused dequant-reduce is
     # what fed.server_reduce != "decode" folds each uplink through
-    up_codec = cfg.fed.codec if cfg is not None else "int8"
-    if up_codec in ("fp16", "int8"):
+    if fed.codec in ("fp16", "int8"):
         profiles.append(profile_agg_fuse(
-            num_clients=max(2, num_clients), codec=up_codec,
-            use_kernel=bool(cfg and cfg.fed.kernel_aggregation),
-            interpret=True, hw=hw, runs=runs))
+            num_clients=num_clients, codec=fed.codec,
+            use_kernel=fed.kernel_aggregation,
+            interpret=fed.kernel_interpret, runs=runs))
     return {p.name: p.to_dict() for p in profiles}

@@ -22,7 +22,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
-from repro.roofline.hw import TPU_V5E, HwSpec
+from repro.roofline.hw import HwSpec
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -35,12 +35,8 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() returns a dict on jax >= 0.5 but a
-    one-element list of dicts on 0.4.x — normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """compiled.cost_analysis(), or {} where the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 # e.g.  %ag = bf16[2,1024,512]{2,1,0} all-gather(...)
 _SHAPE_RE = re.compile(
@@ -121,23 +117,25 @@ class RooflineReport:
         return json.dumps(self.to_dict(), indent=2)
 
 
-def kernel_terms(compiled, hw: HwSpec = TPU_V5E) -> Dict[str, float]:
+def kernel_terms(compiled, hw: Optional[HwSpec]
+                 ) -> Dict[str, Optional[float]]:
     """Roofline terms of ONE compiled kernel/program — the light-weight
     form the obs profiler (``repro.obs.profile``) feeds from jit artifacts:
     flops / bytes from ``cost_analysis`` plus the compute and memory terms
-    against ``hw``.  No HLO parsing (single-device kernels have no
-    collectives)."""
+    against ``hw`` (None, and the terms None, where the program runs on
+    a device without peaks, such as the host CPU).  No HLO parsing
+    (single-device kernels have no collectives)."""
     ca = cost_analysis_dict(compiled)
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     return {"flops": flops, "bytes_accessed": byts,
-            "compute_term_s": flops / hw.peak_flops_bf16,
-            "memory_term_s": byts / hw.hbm_bw,
+            "compute_term_s": flops / hw.peak_flops_bf16 if hw else None,
+            "memory_term_s": byts / hw.hbm_bw if hw else None,
             "arithmetic_intensity": flops / byts if byts else 0.0}
 
 
 def fused_boundary_terms(batch: int, features: int, *,
-                         codec: str = "int8", hw: HwSpec = TPU_V5E,
+                         hw: HwSpec, codec: str = "int8",
                          compiled=None) -> Dict[str, float]:
     """Roofline terms for the fused boundary kernel
     (``kernels/boundary_fuse``): codec qdq + per-example clip + noise
@@ -179,8 +177,8 @@ def fused_boundary_terms(batch: int, features: int, *,
 _WIRE_BYTES = {"none": 4.0, "fp16": 2.0, "int8": 1.0}
 
 
-def agg_fuse_terms(num_clients: int, n: int, *, codec: str = "int8",
-                   hw: HwSpec = TPU_V5E, compiled=None) -> Dict[str, float]:
+def agg_fuse_terms(num_clients: int, n: int, *, hw: HwSpec,
+                   codec: str = "int8", compiled=None) -> Dict[str, float]:
     """Roofline terms for the fused dequant-reduce aggregation kernel
     (``kernels/agg_fuse``): ``num_clients`` compressed client wires of
     ``n`` elements -> one fp32 weighted mean, per-client scales applied
@@ -226,8 +224,7 @@ def agg_fuse_terms(num_clients: int, n: int, *, codec: str = "int8",
 
 
 def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
-                     chips: int, model_flops: float,
-                     hw: HwSpec = TPU_V5E,
+                     chips: int, model_flops: float, hw: HwSpec,
                      hlo_text: Optional[str] = None) -> RooflineReport:
     ca = cost_analysis_dict(compiled)
     flops = float(ca.get("flops", 0.0))

@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
@@ -188,3 +189,19 @@ def stacked_shardings(mesh: Mesh, tree, *, axis: str = "clients",
     logical = jax.tree.map(
         lambda l: Lg(*(("clients",) + (None,) * (l.ndim - 1))), tree)
     return tree_shardings(mesh, rules, tree, logical)
+
+
+def psum_client_rows(kernel, mesh: Mesh, *rows):
+    """``kernel(*rows)`` for a reduce over the leading client axis of
+    ``rows``, on a mesh with a ``clients`` axis.  XLA cannot partition a
+    Mosaic kernel, so each device runs ``kernel`` on its own rows and one
+    ``psum`` adds the partial sums.  Rows are first zero-padded to a
+    multiple of the axis size: a zero-weight client adds nothing."""
+    pad = (-rows[0].shape[0]) % mesh.shape["clients"]
+    if pad:
+        rows = [jnp.pad(r, [(0, pad)] + [(0, 0)] * (r.ndim - 1))
+                for r in rows]
+    return jax.shard_map(
+        lambda *r: jax.lax.psum(kernel(*r), "clients"), mesh=mesh,
+        in_specs=(P("clients"),) * len(rows), out_specs=P(),
+        check_vma=False)(*rows)

@@ -271,3 +271,27 @@ def test_peak_live_trees_is_o1_under_stream(parts):
 def test_server_reduce_validated():
     with pytest.raises(ValueError):
         _cfg(**{"fed.server_reduce": "bogus"})
+
+
+@pytest.mark.parametrize("over", [
+    {"fed.server_reduce": "stream"},
+    {"fed.server_reduce": "decode", "fed.hierarchy_cohorts": 2},
+])
+def test_native_kernel_reduce_without_mesh_form_refuses_mesh(over):
+    """A compiled Mosaic kernel cannot take arrays spanning devices: the
+    stream fold and the edge-cohort reduce have no per-device form, so a
+    multi-device clients mesh is refused up front (decode and batched
+    reduce per device instead)."""
+    from jax.sharding import AbstractMesh
+    from repro.fed.engine import ClientSpec, FederationEngine
+    fed = _cfg(**{"fed.codec": "int8", "fed.kernel_aggregation": True,
+                  **over}).fed
+    specs = [ClientSpec(f"c{i}", 1.0, 1.0) for i in range(4)]
+    mesh = AbstractMesh((4,), ("clients",))
+    with pytest.raises(ValueError, match="clients mesh"):
+        FederationEngine(fed, specs).set_mesh(mesh)
+    for ok in ({"fed.server_reduce": "batched"},
+               {**over, "fed.kernel_interpret": True}):
+        fed = _cfg(**{"fed.codec": "int8", "fed.kernel_aggregation": True,
+                      **ok}).fed
+        FederationEngine(fed, specs).set_mesh(mesh)

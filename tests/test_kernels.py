@@ -147,3 +147,58 @@ def test_fedavg_identity_single_client():
     out = fedavg_trees([t], interpret=True)
     np.testing.assert_allclose(np.asarray(out["x"]), np.asarray(t["x"]),
                                atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# kernels/f16: float16 conversions in 32-bit ops, bit-exact with XLA's
+# ---------------------------------------------------------------------------
+
+def _same_bits(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    nan = np.isnan(want)
+    assert np.isnan(got[nan]).all()
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+def test_f16_bits_to_f32_matches_convert_on_every_pattern():
+    from repro.kernels.f16 import f16_bits_to_f32
+    bits = np.arange(1 << 16, dtype=np.uint16).view(np.int16)
+    _same_bits(f16_bits_to_f32(jnp.asarray(bits)),
+               bits.view(np.float16).astype(np.float32))
+
+
+def test_round_to_f16_matches_convert_roundtrip():
+    from repro.kernels.f16 import round_to_f16
+    f16 = np.arange(1 << 16, dtype=np.uint16).view(np.float16)
+    grid = np.sort(f16[np.isfinite(f16)].astype(np.float32))
+    # every float16, the ties between neighbours and one ulp either side
+    mids = ((grid[1:].astype(np.float64) + grid[:-1]) / 2).astype(np.float32)
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        grid, mids, np.nextafter(mids, np.float32(np.inf)),
+        np.nextafter(mids, np.float32(-np.inf)),
+        np.array([np.inf, -np.inf, np.nan, 65519.996, 65520.0, 1e-45],
+                 np.float32),
+        rng.integers(0, 1 << 32, 1 << 18, dtype=np.uint64)
+        .astype(np.uint32).view(np.float32)])
+    _same_bits(round_to_f16(jnp.asarray(x)),
+               jnp.asarray(x).astype(jnp.float16).astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# reduces on a `clients` mesh: per-device kernel + psum == one kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("clients", [4, 5])
+def test_fedavg_on_clients_mesh_matches_single_device(clients):
+    """Rows split over every host device (5 clients pad with a zero-weight
+    row on 4 devices) reduce to the single-device kernel's mean."""
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()), ("clients",))
+    stacked = jax.random.normal(jax.random.PRNGKey(3), (clients, 5000))
+    w = jnp.arange(1.0, clients + 1.0)
+    want = fedavg_flat(stacked, w, interpret=True)
+    got = fedavg_flat(stacked, w, interpret=True, mesh=mesh)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)

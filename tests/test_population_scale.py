@@ -43,7 +43,7 @@ _MULTI = len(jax.devices()) >= 4
 # ---------------------------------------------------------------------------
 
 def _amesh(n=4):
-    return AbstractMesh((("clients", n),))
+    return AbstractMesh((n,), ("clients",))
 
 
 def test_client_axis_shards_only_when_divisible():
@@ -58,7 +58,7 @@ def test_client_axis_shards_only_when_divisible():
 
 
 def test_client_axis_rules_fall_back_without_clients_axis():
-    m = AbstractMesh((("data", 2),))
+    m = AbstractMesh((2,), ("data",))
     rules = client_axis_rules(m)
     assert rules.mesh_axes_for("clients") is None
     assert logical_spec(m, rules, (8,), ("clients",)) == P()

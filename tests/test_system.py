@@ -84,3 +84,57 @@ def test_dryrun_decode_path_on_host_mesh():
     mesh = make_host_mesh()
     lowered, compiled, _ = lower_one(cfg, mesh)
     assert compiled is not None
+
+
+# ---------------------------------------------------------------------------
+# launch/cache: where the persistent compilation cache lands
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = """
+import json, jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+from repro.launch.cache import use_compile_cache
+path = use_compile_cache()
+if {compile}:
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+print(json.dumps({{"path": path,
+                  "config": jax.config.jax_compilation_cache_dir}}))
+"""
+
+
+def _cache_probe(env_dir, compile_):
+    import json
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=compile_)],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_uses_the_environment_directory(tmp_path):
+    import os
+    from repro.launch.cache import CHECKOUT_CACHE
+    before = sorted(os.listdir(CHECKOUT_CACHE)) \
+        if os.path.isdir(CHECKOUT_CACHE) else None
+    got = _cache_probe(str(tmp_path), True)
+    assert got == {"path": str(tmp_path), "config": str(tmp_path)}
+    assert os.listdir(tmp_path)                  # the entry landed there
+    after = sorted(os.listdir(CHECKOUT_CACHE)) \
+        if os.path.isdir(CHECKOUT_CACHE) else None
+    assert after == before                       # and nowhere else
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    import os
+    from repro.launch.cache import CHECKOUT_CACHE
+    got = _cache_probe(None, False)
+    assert got == {"path": CHECKOUT_CACHE, "config": CHECKOUT_CACHE}
+    root = os.path.dirname(CHECKOUT_CACHE)
+    assert os.path.isfile(os.path.join(root, "chip_smoke.py"))
