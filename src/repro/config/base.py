@@ -67,7 +67,6 @@ SERVER_REDUCES = ("decode", "stream", "batched")
 PRIVACY_MODES = ("dp_sgd", "uplink")
 CONTROL_MODES = ("frozen", "adaptive")
 CONTROLLERS = ("codec", "sigma", "split", "deadline")
-OBS_TRACE_CLOCKS = ("virtual", "wall", "both")
 OBS_SINKS = ("trace", "metrics", "feedback", "alerts", "digests")
 # what a fatal health verdict does to the run (obs/health.py)
 HEALTH_POLICIES = ("record", "warn", "abort", "rollback")
@@ -636,7 +635,7 @@ class ObsConfig:
 
       * spans for round -> download -> client-execution -> split-segment ->
         boundary-crossing -> uplink -> aggregate on the engine's virtual
-        clock (plus wall-clock host spans), exported as Chrome-trace JSON;
+        clock, exported as Chrome-trace JSON;
       * a typed metric registry fed from each round's ``RoundFeedback``,
         snapshotted to ``metrics.jsonl``;
       * the full ``RoundFeedback`` + knob-decision history as JSONL, enough
@@ -654,7 +653,6 @@ class ObsConfig:
     # which sinks are live when enabled; subset of OBS_SINKS
     sinks: Tuple[str, ...] = ("trace", "metrics", "feedback", "alerts",
                               "digests")
-    trace_clock: str = "virtual"       # virtual | wall | both (export clocks)
     # cap batches whose segment/boundary phases are traced per client per
     # round (0 = no cap); rounds beyond the cap still get client spans
     trace_batches: int = 0
@@ -665,7 +663,6 @@ class ObsConfig:
     health: HealthConfig = field(default_factory=HealthConfig)
 
     def __post_init__(self) -> None:
-        _check_name("obs", "trace_clock", self.trace_clock, OBS_TRACE_CLOCKS)
         for s in self.sinks:
             _check_name("obs", "sinks", s, OBS_SINKS)
 

@@ -75,6 +75,7 @@ from repro.obs import FlightRecorder, profile_engine_kernels
 from repro.obs.digest import RoundDigest, state_digest, tree_digest
 from repro.obs.health import (SEV_FATAL, HealthAbort, HealthAlert,
                               HealthMonitor)
+from repro.obs.trace import SPAN_GENERATOR, SPAN_INPUT, SPAN_ROUND, to_host
 from repro.optim import make_optimizer
 from repro.privacy.defenses import (RDPAccountant, make_dp_d_step,
                                     make_uplink_stage)
@@ -470,10 +471,11 @@ class FSLGANTrainer:
         ``real``."""
         st = self.state
         rs, fs = [], []
-        for _ in range(steps):
-            rs.append(self._sample_real(cid, self.batch_size))
-            fs.append(self._gen(st.g_params, self._z(self.batch_size)))
-        return jnp.stack(rs), jnp.stack(fs)
+        with jax.profiler.TraceAnnotation(SPAN_INPUT):
+            for _ in range(steps):
+                rs.append(self._sample_real(cid, self.batch_size))
+                fs.append(self._gen(st.g_params, self._z(self.batch_size)))
+            return jnp.stack(rs), jnp.stack(fs)
 
     def _bind_round(self, batches_per_client: int, backend: str
                     ) -> RoundExecutor:
@@ -653,7 +655,7 @@ class FSLGANTrainer:
             for dev, names in ex.segments[:-1]:
                 for name in names:
                     x = ex.apply_layer(name, params, x)
-                dcors.append(float(distance_correlation(x0, x)))
+                dcors.append(to_host(distance_correlation(x0, x)))
             out[cid] = tuple(dcors)
         return out
 
@@ -715,13 +717,15 @@ class FSLGANTrainer:
         return rolled, not poisoned, abort_alert
 
     def _g_updates(self, d_avg, batches: int) -> List[float]:
-        """Server G update against the averaged D (never touches real data)."""
+        """Server G update against the averaged D (never touches real data),
+        each loss read back to the host after its step."""
         st = self.state
         g_losses = []
-        for _ in range(batches):
-            st.g_params, st.g_opt, gl = self._g_step(
-                st.g_params, st.g_opt, d_avg, self._z(self.batch_size))
-            g_losses.append(float(gl))
+        with jax.profiler.TraceAnnotation(SPAN_GENERATOR):
+            for _ in range(batches):
+                st.g_params, st.g_opt, gl = self._g_step(
+                    st.g_params, st.g_opt, d_avg, self._z(self.batch_size))
+                g_losses.append(to_host(gl))
         return g_losses
 
     def _record(self, metrics: Dict[str, float]) -> Dict[str, float]:
@@ -765,7 +769,16 @@ class FSLGANTrainer:
         When the recorder's ``digests`` sink is on, the round also commits
         a content digest of the post-action global state
         (``digests.jsonl``).
+
+        The whole round is one ``fsl.round`` step span on the profiler's
+        clock, numbered by the round (``obs/trace.py``).
         """
+        with jax.profiler.StepTraceAnnotation(SPAN_ROUND,
+                                              step_num=self.state.step):
+            return self._train_round(batches_per_client, backend)
+
+    def _train_round(self, batches_per_client: int,
+                     backend: Optional[str]) -> Dict[str, float]:
         backend = backend or self.cfg.fed.backend
         st = self.state
         if self.monitor is not None \

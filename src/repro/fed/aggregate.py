@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.kernels.agg_fuse.ops import (dequant_acc_flat, dequant_reduce_flat,
                                         scatter_acc_flat)
 from repro.fed.transport import apply_delta
+from repro.obs.trace import to_host
 
 __all__ = ["StreamingAggregator", "batched_reduce", "codec_rel_error",
            "decode_enc", "fused_decode_apply"]
@@ -97,8 +98,8 @@ def codec_rel_error(codec_name: str, enc: EncTree, delta) -> float:
             if name == "int8":
                 dec = dec * meta
             num += jnp.sum((dec - f) ** 2)
-    return math.sqrt(max(float(num), 0.0)) / max(math.sqrt(float(den)),
-                                                 1e-12)
+    return math.sqrt(max(to_host(num), 0.0)) / max(math.sqrt(to_host(den)),
+                                                   1e-12)
 
 
 class StreamingAggregator:
@@ -174,8 +175,8 @@ class StreamingAggregator:
             return None
         if name == "none":
             return 0.0
-        return math.sqrt(max(float(num), 0.0)) \
-            / max(math.sqrt(float(den)), 1e-12)
+        return math.sqrt(max(to_host(num), 0.0)) \
+            / max(math.sqrt(to_host(den)), 1e-12)
 
     def finalize(self):
         """Weighted mean tree (template structure/shapes/dtypes), or

@@ -47,6 +47,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.fed.aggregate import (StreamingAggregator, batched_reduce,
                                  codec_rel_error, decode_enc,
                                  fused_decode_apply)
@@ -57,6 +59,7 @@ from repro.fed.programs import as_program
 from repro.fed.transport import (LinkModel, TrafficLedger, apply_delta,
                                  delta_tree, make_codec, tree_bytes,
                                  tree_rel_error)
+from repro.obs.trace import SPAN_REDUCE, SPAN_SYNC
 
 # legacy program shape: local_update(client_id, start_params)
 #   -> (trained_params, info_dict)
@@ -259,7 +262,12 @@ class FederationEngine:
             if self.uplink_stage is not None:
                 delta = self.uplink_stage(cid, delta)
             dec, nbytes = codec.roundtrip(delta)
-            err = tree_rel_error(dec, delta) if codec.encodes_delta else 0.0
+            err = 0.0
+            if codec.encodes_delta:
+                # reads both trees back to the host, leaf by leaf: one
+                # span for the call
+                with jax.profiler.TraceAnnotation(SPAN_SYNC):
+                    err = tree_rel_error(dec, delta)
             return apply_delta(base_tree, dec), nbytes, err
         dec, nbytes = codec.roundtrip(params)
         return dec, nbytes, 0.0
@@ -469,84 +477,87 @@ class FederationEngine:
                 runnable.append(cid)
         results = program.run(runnable, global_tree)
 
-        # compressed-domain reduce ("stream"/"batched"): landed uplinks
-        # fold as WIRE payloads — no per-client decoded tree is staged
-        reduce_mode = self.server_reduce
-        agg: Optional[StreamingAggregator] = None
-        staged: List[Tuple[Any, float]] = []      # batched: (enc, weight)
-        is_delta = False
-        if reduce_mode != "decode":
-            agg = StreamingAggregator(self.codec_name,
-                                      use_kernel=self.cfg.kernel_aggregation,
-                                      interpret=self.cfg.kernel_interpret)
-            agg.init(global_tree)
+        # the server reduce: codec round trips, then the fold or FedAvg
+        with jax.profiler.TraceAnnotation(SPAN_REDUCE):
+            # compressed-domain reduce ("stream"/"batched"): landed uplinks
+            # fold as WIRE payloads — no per-client decoded tree is staged
+            reduce_mode = self.server_reduce
+            agg: Optional[StreamingAggregator] = None
+            staged: List[Tuple[Any, float]] = []      # batched: (enc, weight)
+            is_delta = False
+            if reduce_mode != "decode":
+                agg = StreamingAggregator(
+                    self.codec_name, use_kernel=self.cfg.kernel_aggregation,
+                    interpret=self.cfg.kernel_interpret)
+                agg.init(global_tree)
 
-        for res in results:
-            cid = res.client_id
-            spec = self.specs[cid]
-            if reduce_mode == "decode":
-                decoded, up_b, cerr = self._codec_roundtrip(
-                    cid, global_tree, res.params)
-            else:
-                enc, up_b, delta, is_delta = self._encode_uplink(
-                    cid, global_tree, res.params)
-            finish = down_t[cid] + spec.compute_time_s \
-                + self.uplink.transfer_time(up_b)
-            rep.traffic.record(cid, up=up_b, down=db(cid),
-                               lan=self._lan_by.get(cid, 0))
-            rep.client_infos.append((cid, res.info))
-            rep.finish_s[cid] = finish
-            if reduce_mode == "decode":
-                rep.codec_error[cid] = cerr
-            if deadline and finish > deadline:
-                if reduce_mode != "decode":
-                    # ran but never folds: measure the codec's cost
-                    # without decoding the dropped update
+            for res in results:
+                cid = res.client_id
+                spec = self.specs[cid]
+                if reduce_mode == "decode":
+                    decoded, up_b, cerr = self._codec_roundtrip(
+                        cid, global_tree, res.params)
+                else:
+                    enc, up_b, delta, is_delta = self._encode_uplink(
+                        cid, global_tree, res.params)
+                finish = down_t[cid] + spec.compute_time_s \
+                    + self.uplink.transfer_time(up_b)
+                rep.traffic.record(cid, up=up_b, down=db(cid),
+                                   lan=self._lan_by.get(cid, 0))
+                rep.client_infos.append((cid, res.info))
+                rep.finish_s[cid] = finish
+                if reduce_mode == "decode":
+                    rep.codec_error[cid] = cerr
+                if deadline and finish > deadline:
+                    if reduce_mode != "decode":
+                        # ran but never folds: measure the codec's cost
+                        # without decoding the dropped update
+                        rep.codec_error[cid] = codec_rel_error(
+                            self.codec_name, enc, delta)
+                    # ran, but its update is late: nothing commits, not
+                    # even its optimizer state
+                    rep.stragglers.append(cid)
+                    continue
+                rep.participated.append(cid)
+                if res.opt_state is not None:
+                    rep.opt_states[cid] = res.opt_state
+                rep.staleness[cid] = 0
+                rep.staleness_events.append(0)
+                finishes.append(finish)
+                if reduce_mode == "stream":
+                    # fold now; the rel error rides the same traversal
+                    err = agg.fold(enc, spec.weight if self.weighted else 1.0,
+                                   delta=delta)
+                    rep.codec_error[cid] = 0.0 if err is None else err
+                elif reduce_mode == "batched":
+                    staged.append((enc, spec.weight if self.weighted else 1.0))
                     rep.codec_error[cid] = codec_rel_error(
                         self.codec_name, enc, delta)
-                rep.stragglers.append(cid)     # ran, but its update is late
-                continue                       # nothing commits — not even
-                                               # its optimizer state
-            rep.participated.append(cid)
-            if res.opt_state is not None:
-                rep.opt_states[cid] = res.opt_state
-            rep.staleness[cid] = 0
-            rep.staleness_events.append(0)
-            finishes.append(finish)
-            if reduce_mode == "stream":
-                # fold now; the rel error rides the same traversal
-                err = agg.fold(enc, spec.weight if self.weighted else 1.0,
-                               delta=delta)
-                rep.codec_error[cid] = 0.0 if err is None else err
-            elif reduce_mode == "batched":
-                staged.append((enc, spec.weight if self.weighted else 1.0))
-                rep.codec_error[cid] = codec_rel_error(
-                    self.codec_name, enc, delta)
-            else:
-                self.policy.on_update(
-                    global_tree, ClientUpdate(cid, decoded, spec.weight,
-                                              0, self.clock + finish))
+                else:
+                    self.policy.on_update(
+                        global_tree, ClientUpdate(cid, decoded, spec.weight,
+                                                  0, self.clock + finish))
 
-        if reduce_mode == "decode":
-            new_global = self.policy.on_round_end(global_tree)
-            rep.peak_live_trees = len(rep.participated)
-        else:
-            if reduce_mode == "stream":
-                mean = agg.finalize()
-            elif staged:
-                mean = batched_reduce(
-                    self.codec_name, [e for e, _ in staged],
-                    [w for _, w in staged], global_tree,
-                    use_kernel=self.cfg.kernel_aggregation,
-                    interpret=self.cfg.kernel_interpret, mesh=self.mesh)
+            if reduce_mode == "decode":
+                new_global = self.policy.on_round_end(global_tree)
+                rep.peak_live_trees = len(rep.participated)
             else:
-                mean = None
-            if mean is None:
-                new_global = global_tree
-            else:
-                new_global = apply_delta(global_tree, mean) if is_delta \
-                    else mean
-            rep.peak_live_trees = 1 if rep.participated else 0
+                if reduce_mode == "stream":
+                    mean = agg.finalize()
+                elif staged:
+                    mean = batched_reduce(
+                        self.codec_name, [e for e, _ in staged],
+                        [w for _, w in staged], global_tree,
+                        use_kernel=self.cfg.kernel_aggregation,
+                        interpret=self.cfg.kernel_interpret, mesh=self.mesh)
+                else:
+                    mean = None
+                if mean is None:
+                    new_global = global_tree
+                else:
+                    new_global = apply_delta(global_tree, mean) if is_delta \
+                        else mean
+                rep.peak_live_trees = 1 if rep.participated else 0
         if rep.participated:
             self.version += 1
         # the sync barrier releases at the slowest survivor — or at the

@@ -44,6 +44,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import SPAN_CLIENT_STEP, to_host
+
 # loss_fn(params, real_batch, fake_batch) -> scalar loss
 LossFn = Callable[[Any, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
@@ -323,20 +325,22 @@ class LocalProgram:
                    lr: Optional[float] = None, key=None,
                    cid: Optional[str] = None
                    ) -> Tuple[Any, Any, List[float]]:
-        """One client's round: T jitted steps over (T, B, ...) batches.
+        """One client's round: T jitted steps over (T, B, ...) batches,
+        each loss read back to the host after its step (T syncs).
         ``cid`` selects the client's split-signature step (monolithic when
         omitted or unlisted)."""
-        lr_arr = jnp.float32(self.base_lr if lr is None else lr)
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        step = self._step(self.signature_for(cid) if cid is not None
-                          else None)
-        losses: List[float] = []
-        for t in range(reals.shape[0]):
-            params, opt, l = step(params, opt, reals[t], fakes[t],
-                                  lr_arr, jax.random.fold_in(key, t))
-            losses.append(float(l))
-        return params, opt, losses
+        with jax.profiler.TraceAnnotation(SPAN_CLIENT_STEP):
+            lr_arr = jnp.float32(self.base_lr if lr is None else lr)
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            step = self._step(self.signature_for(cid) if cid is not None
+                              else None)
+            losses: List[float] = []
+            for t in range(reals.shape[0]):
+                params, opt, l = step(params, opt, reals[t], fakes[t],
+                                      lr_arr, jax.random.fold_in(key, t))
+                losses.append(to_host(l))
+            return params, opt, losses
 
     def run_vectorized(self, stacked_params, stacked_opt, reals, fakes, *,
                        lrs=None, keys=None, mask=None, signature=None):
@@ -538,18 +542,22 @@ class RoundExecutor:
                     stacked_m = self._shard_stacked(
                         (stacked_p, stacked_o, stacked_r, stacked_f,
                          stacked_k, stacked_m))
-            new_p, new_o, losses = self.program.run_vectorized(
-                stacked_p, stacked_o, stacked_r, stacked_f,
-                lrs=[self.lr_for(cids[i]) for i in idxs],
-                keys=stacked_k, mask=stacked_m, signature=sig)
+            with jax.profiler.TraceAnnotation(SPAN_CLIENT_STEP):
+                new_p, new_o, losses = self.program.run_vectorized(
+                    stacked_p, stacked_o, stacked_r, stacked_f,
+                    lrs=[self.lr_for(cids[i]) for i in idxs],
+                    keys=stacked_k, mask=stacked_m, signature=sig)
             for j, i in enumerate(idxs):
                 cid, s = cids[i], steps[i]
                 p = jax.tree.map(lambda x: x[j], new_p)
                 o = jax.tree.map(lambda x: x[j], new_o)
                 self._opt_overlay[cid] = o
+                # each loss is its own device-to-host read: s syncs per
+                # client, sum(steps) for the group, all after the group's
+                # one dispatch
                 out[i] = ClientResult(
                     cid, p, o,
-                    {"losses": [float(l) for l in losses[j, :s]],
+                    {"losses": [to_host(l) for l in losses[j, :s]],
                      "steps": s})
         return out
 

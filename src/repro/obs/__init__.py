@@ -3,7 +3,8 @@ metrics, recording + replay, profiling (ISSUE 6 / ROADMAP item 4), and the
 detection layer over it — health monitors, content digests, run diffing,
 bench regression gating (ISSUE 7).
 
-  * :mod:`repro.obs.trace`    — two-clock nested spans + Chrome-trace export
+  * :mod:`repro.obs.trace`    — virtual-clock nested spans + Chrome-trace
+    export; the round's span names on the profiler's clock
   * :mod:`repro.obs.metrics`  — typed counter/gauge/histogram registry + JSONL
   * :mod:`repro.obs.recorder` — per-run persistence of feedback/knobs/metrics
     /alerts/digests

@@ -98,11 +98,10 @@ class FlightRecorder:
 
     def __init__(self, run_dir: str, *, run_id: Optional[str] = None,
                  sinks=("trace", "metrics", "feedback", "alerts", "digests"),
-                 trace_clock: str = "virtual", trace_batches: int = 0):
+                 trace_batches: int = 0):
         self.run_dir = run_dir
         self.run_id = run_id or os.path.basename(run_dir)
         self.sinks = tuple(sinks)
-        self.trace_clock = trace_clock
         self.trace_batches = int(trace_batches)
         os.makedirs(run_dir, exist_ok=True)
         self.tracer = Tracer(self.run_id)
@@ -137,8 +136,7 @@ class FlightRecorder:
         rid = run_id or obs.run_id \
             or f"{cfg.model.name or 'run'}-{os.getpid()}"
         return cls(os.path.join(obs.out_dir, rid), run_id=rid,
-                   sinks=obs.sinks, trace_clock=obs.trace_clock,
-                   trace_batches=obs.trace_batches)
+                   sinks=obs.sinks, trace_batches=obs.trace_batches)
 
     def path(self, name: str) -> str:
         return os.path.join(self.run_dir, name)
@@ -212,8 +210,7 @@ class FlightRecorder:
             return self._trace_path
         if len(self.tracer.spans) == self._flushed_spans:
             return self._trace_path
-        self._trace_path = self.tracer.export_chrome(
-            self.path(TRACE), self.trace_clock)
+        self._trace_path = self.tracer.export_chrome(self.path(TRACE))
         self._flushed_spans = len(self.tracer.spans)
         return self._trace_path
 

@@ -26,13 +26,16 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
 from repro.config import RunConfig
-from repro.control.controllers import ControllerSuite, make_controllers
+# the module, not its names: the federation runtime imports this package
+# (for the span names of obs/trace) while repro.control.controllers is
+# still being imported
+from repro.control import controllers
 from repro.control.feedback import (ControlKnobs, RoundFeedback,
                                     knobs_from_config)
 from repro.obs.recorder import RunRecord, load_run
 
 
-def replay_decisions(suite: ControllerSuite,
+def replay_decisions(suite: controllers.ControllerSuite,
                      history: Sequence[RoundFeedback],
                      initial_knobs: ControlKnobs) -> List[ControlKnobs]:
     """The pure decision fold: what knobs were in force during each
@@ -47,10 +50,10 @@ def replay_decisions(suite: ControllerSuite,
     return decisions
 
 
-def suite_from_manifest(manifest: dict) -> ControllerSuite:
+def suite_from_manifest(manifest: dict) -> controllers.ControllerSuite:
     """Rebuild the exact live controller suite from a run manifest."""
     cfg = RunConfig.from_dict(manifest["config"])
-    return make_controllers(
+    return controllers.make_controllers(
         cfg, leaf_sizes=manifest["leaf_sizes"],
         steps_per_round_hint=manifest.get("steps_per_round_hint", 1))
 
@@ -76,7 +79,8 @@ class ReplayResult:
 
 
 def replay_run(run_dir: str, *,
-               suite: Optional[ControllerSuite] = None) -> ReplayResult:
+               suite: Optional[controllers.ControllerSuite] = None
+               ) -> ReplayResult:
     """Load a recorded run and replay its feedback through the (rebuilt or
     provided) controller suite; compare against the recorded knob log.
 
@@ -98,7 +102,7 @@ def replay_run(run_dir: str, *,
         if cfg.control.mode == "adaptive" and cfg.control.controllers:
             suite = suite_from_manifest(rec.manifest)
         else:
-            suite = ControllerSuite([])
+            suite = controllers.ControllerSuite([])
     decisions = replay_decisions(suite, rec.feedback, knobs_from_config(cfg))
     result = ReplayResult(record=rec, decisions=decisions)
     for r, (got, want) in enumerate(zip(decisions, rec.knobs)):

@@ -1,47 +1,64 @@
-"""Two-clock span tracing for the federated split engine.
+"""Span tracing for the federated split engine, on two kinds of clock.
 
-The engine advances a *virtual* clock (the paper's analytic time model:
-download + segment compute + LAN hops + uplink), while the tensor math runs
-on the host in *wall* time.  A :class:`Span` therefore carries both clocks:
-``v_start``/``v_end`` in virtual seconds (NaN when the span is wall-only)
-and ``wall_start``/``wall_end`` in host seconds (NaN when the span was
-placed retroactively from priced times — the engine knows a client's whole
-virtual timeline the moment it schedules it, so most spans are recorded
-with :meth:`Tracer.record` rather than timed live).
+**Virtual clock.**  The engine advances a *virtual* clock (the paper's
+analytic time model: download + segment compute + LAN hops + uplink).  A
+:class:`Span` carries ``v_start``/``v_end`` in virtual seconds; the engine
+knows a client's whole virtual timeline the moment it schedules it, so
+spans are recorded with :meth:`Tracer.record` after the fact, not timed
+live.  Hierarchy is explicit: every span holds its parent's id, so round
+-> client-execution -> split-segment -> boundary-crossing nests exactly
+the way the engine composed the round, and a trace viewer shows the LAN
+hops inside the compute window they actually occupy.  :func:`to_chrome`
+exports the Chrome-trace / Perfetto JSON object model
+(``{"traceEvents": [...]}``, "X" complete events, one tid lane per
+track), loadable in ``ui.perfetto.dev`` or ``chrome://tracing``;
+:func:`validate_chrome_trace` is the schema check CI runs on the exported
+file.
 
-Hierarchy is explicit: every span holds its parent's id, so round ->
-client-execution -> split-segment -> boundary-crossing nests exactly the
-way the engine composed the round, and a trace viewer shows the LAN hops
-inside the compute window they actually occupy.
-
-:func:`to_chrome` exports the Chrome-trace / Perfetto JSON object model
-(``{"traceEvents": [...]}``, "X" complete events, one pid per clock, one
-tid lane per track), loadable in ``ui.perfetto.dev`` or
-``chrome://tracing``; :func:`validate_chrome_trace` is the schema check CI
-runs on the exported file.
+**The profiler's clock.**  The round's host layers are bounded by
+``jax.profiler`` annotations named by the ``SPAN_*`` constants below.
+They land on the profiler's host plane, on the same clock as the
+device's "XLA Ops", so a device trace can say what the host was doing in
+each idle gap.  Parents are given by time nesting on the host thread;
+the round is a step annotation whose ``step_num`` is the round index.
+With no trace being taken an annotation costs about a microsecond, so
+the spans are always on.
 """
 from __future__ import annotations
 
 import json
 import math
-import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
+
+import jax
 
 NAN = float("nan")
 
-# Chrome-trace pids: one synthetic "process" per clock, so both timelines
-# coexist in one file without colliding timestamps.
+# Chrome-trace pid of the virtual clock's "process"
 PID_VIRTUAL = 1
-PID_WALL = 2
 
-TRACE_CLOCKS = ("virtual", "wall", "both")
+# Spans on the profiler's clock.  A trace of a window finds each layer's
+# host time, and the host work behind each device idle gap, by these names.
+SPAN_ROUND = "fsl.round"            # one round (a step annotation)
+SPAN_INPUT = "fsl.input"            # one client's batches sampled
+SPAN_CLIENT_STEP = "fsl.client_step"  # one client's (or group's) steps
+SPAN_SYNC = "fsl.sync"              # the host blocked on a device read
+SPAN_REDUCE = "fsl.reduce"          # server reduce of the uplinks
+SPAN_GENERATOR = "fsl.generator"    # the server's G steps
+
+
+def to_host(x) -> float:
+    """``float(x)`` of a device scalar: one blocking device-to-host read,
+    inside an ``fsl.sync`` span, so that the reads are counted where they
+    happen."""
+    with jax.profiler.TraceAnnotation(SPAN_SYNC):
+        return float(x)
 
 
 @dataclass(frozen=True)
 class Span:
-    """One named interval on one track, on one or both clocks."""
+    """One named interval on one track of the virtual clock."""
     span_id: int
     parent_id: Optional[int]
     name: str
@@ -49,8 +66,6 @@ class Span:
     track: str                    # viewer lane (client id, device id, server)
     v_start: float = NAN          # virtual seconds (engine clock)
     v_end: float = NAN
-    wall_start: float = NAN       # host seconds since tracer start
-    wall_end: float = NAN
     args: Dict[str, Any] = field(default_factory=dict)
 
     @property
@@ -61,24 +76,13 @@ class Span:
     def has_virtual(self) -> bool:
         return math.isfinite(self.v_start) and math.isfinite(self.v_end)
 
-    @property
-    def has_wall(self) -> bool:
-        return math.isfinite(self.wall_start) and math.isfinite(self.wall_end)
-
 
 class Tracer:
-    """Append-only span log with explicit parents and a wall-span stack.
+    """Append-only log of virtually-timed spans with explicit parents.
 
-    Two recording styles, matching how the engine knows about time:
-
-      * :meth:`record` — a span whose VIRTUAL interval is already priced
-        (the engine computes a client's download/compute/uplink times when
-        it schedules the client, not as they "happen"); parent defaults to
-        the innermost open wall span so retroactive virtual spans still
-        nest under the host phase that produced them.
-      * :meth:`span` — a context manager that measures the WALL interval
-        of the enclosed host work (``program.run``, codec round-trips, jit
-        compiles) and maintains the nesting stack.
+    :meth:`record` appends a span whose VIRTUAL interval is already priced
+    (the engine computes a client's download/compute/uplink times when it
+    schedules the client, not as they "happen").
 
     ``set_virtual_offset`` re-bases subsequent virtual times: the trainer
     calls it when it rebuilds the engine (whose virtual clock restarts at
@@ -88,15 +92,10 @@ class Tracer:
     def __init__(self, run_id: str = "run"):
         self.run_id = run_id
         self.spans: List[Span] = []
-        self._stack: List[int] = []
         self._next_id = 0
-        self._wall0 = time.perf_counter()
         self._v_offset = 0.0
 
     # ------------------------------------------------------------------
-    def _now(self) -> float:
-        return time.perf_counter() - self._wall0
-
     def set_virtual_offset(self, offset_s: float) -> None:
         self._v_offset = float(offset_s)
 
@@ -114,38 +113,16 @@ class Tracer:
     def record(self, name: str, *, cat: str, track: str,
                v_start: float, v_end: float,
                parent: Optional[int] = None,
-               args: Optional[Dict[str, Any]] = None,
-               wall_start: float = NAN, wall_end: float = NAN) -> int:
+               args: Optional[Dict[str, Any]] = None) -> int:
         """Append a virtually-timed span; returns its id (for children)."""
-        if parent is None and self._stack:
-            parent = self._stack[-1]
         sid = self._next_id
         self._next_id += 1
         self.spans.append(Span(
             sid, parent, name, cat, track,
             v_start=self._v_offset + float(v_start),
             v_end=self._v_offset + float(v_end),
-            wall_start=wall_start, wall_end=wall_end,
             args=dict(args or {})))
         return sid
-
-    @contextmanager
-    def span(self, name: str, *, cat: str = "host", track: str = "host",
-             args: Optional[Dict[str, Any]] = None) -> Iterator[int]:
-        """Wall-clocked span around host work; nests via the stack."""
-        parent = self._stack[-1] if self._stack else None
-        sid = self._next_id
-        self._next_id += 1
-        self._stack.append(sid)
-        t0 = self._now()
-        try:
-            yield sid
-        finally:
-            self._stack.pop()
-            self.spans.append(Span(
-                sid, parent, name, cat, track,
-                wall_start=t0, wall_end=self._now(),
-                args=dict(args or {})))
 
     # ------------------------------------------------------------------
     def children(self, span_id: Optional[int]) -> List[Span]:
@@ -161,11 +138,9 @@ class Tracer:
         raise KeyError(span_id)
 
     # ------------------------------------------------------------------
-    def to_chrome(self, clock: str = "virtual") -> Dict[str, Any]:
-        """Chrome-trace object: X events in microseconds, pid per clock."""
-        if clock not in TRACE_CLOCKS:
-            raise ValueError(f"clock={clock!r}; expected one of "
-                             f"{list(TRACE_CLOCKS)}")
+    def to_chrome(self) -> Dict[str, Any]:
+        """Chrome-trace object: X events in microseconds on the virtual
+        clock's pid, one tid per track."""
         tids: Dict[str, int] = {}
 
         def tid(track: str) -> int:
@@ -174,9 +149,9 @@ class Tracer:
             return tids[track]
 
         events: List[Dict[str, Any]] = []
-        want_v = clock in ("virtual", "both")
-        want_w = clock in ("wall", "both")
         for s in self.spans:
+            if not s.has_virtual:
+                continue
             # args must be JSON-finite: a trace with NaN breaks strict
             # Chrome-trace parsers, so non-finite values are stringified
             args = {k: (v if not isinstance(v, float) or math.isfinite(v)
@@ -184,35 +159,24 @@ class Tracer:
             args["span_id"] = s.span_id
             if s.parent_id is not None:
                 args["parent_id"] = s.parent_id
-            if want_v and s.has_virtual:
-                events.append({
-                    "name": s.name, "cat": s.cat, "ph": "X",
-                    "pid": PID_VIRTUAL, "tid": tid(s.track),
-                    "ts": s.v_start * 1e6,
-                    "dur": max(0.0, s.v_dur) * 1e6,
-                    "args": args})
-            if want_w and s.has_wall:
-                events.append({
-                    "name": s.name, "cat": s.cat, "ph": "X",
-                    "pid": PID_WALL, "tid": tid(s.track),
-                    "ts": s.wall_start * 1e6,
-                    "dur": max(0.0, s.wall_end - s.wall_start) * 1e6,
-                    "args": args})
-        meta: List[Dict[str, Any]] = []
-        for pid, pname, on in ((PID_VIRTUAL, "virtual clock", want_v),
-                               (PID_WALL, "wall clock", want_w)):
-            if not on:
-                continue
-            meta.append({"name": "process_name", "ph": "M", "pid": pid,
-                         "tid": 0, "args": {"name": pname}})
-            for track, t in sorted(tids.items(), key=lambda kv: kv[1]):
-                meta.append({"name": "thread_name", "ph": "M", "pid": pid,
-                             "tid": t, "args": {"name": track}})
+            events.append({
+                "name": s.name, "cat": s.cat, "ph": "X",
+                "pid": PID_VIRTUAL, "tid": tid(s.track),
+                "ts": s.v_start * 1e6,
+                "dur": max(0.0, s.v_dur) * 1e6,
+                "args": args})
+        meta: List[Dict[str, Any]] = [
+            {"name": "process_name", "ph": "M", "pid": PID_VIRTUAL,
+             "tid": 0, "args": {"name": "virtual clock"}}]
+        for track, t in sorted(tids.items(), key=lambda kv: kv[1]):
+            meta.append({"name": "thread_name", "ph": "M",
+                         "pid": PID_VIRTUAL, "tid": t,
+                         "args": {"name": track}})
         return {"traceEvents": meta + events, "displayTimeUnit": "ms",
-                "otherData": {"run_id": self.run_id, "clock": clock}}
+                "otherData": {"run_id": self.run_id, "clock": "virtual"}}
 
-    def export_chrome(self, path: str, clock: str = "virtual") -> str:
-        obj = self.to_chrome(clock)
+    def export_chrome(self, path: str) -> str:
+        obj = self.to_chrome()
         validate_chrome_trace(obj)
         with open(path, "w") as f:
             # allow_nan=False: a file Perfetto rejects must fail HERE
