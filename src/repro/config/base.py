@@ -374,10 +374,11 @@ class FedConfig:
     """
     mode: str = "sync"                 # sync | fedasync | fedbuff
     # client-program backend (fed/programs.py): how the local round is
-    # compiled.  "loop" = per-client jitted steps (seed dispatch, bit-exact
-    # reference); "vectorized" = one jitted vmap-over-clients /
-    # scan-over-batches program per dispatch.  Orthogonal to scheduling
-    # and privacy — every mode x backend x privacy cell is supported.
+    # compiled.  "loop" = per-client jitted steps, dispatched back to back
+    # with one loss read per client (bit-exact reference); "vectorized" =
+    # one jitted vmap-over-clients / scan-over-batches program per
+    # dispatch.  Orthogonal to scheduling and privacy — every mode x
+    # backend x privacy cell is supported.
     backend: str = "loop"              # loop | vectorized
     # per-client local-round schedules, keyed by client id; unlisted
     # clients use the defaults (lr_scale 1.0 / the round's

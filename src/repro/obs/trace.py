@@ -29,9 +29,11 @@ from __future__ import annotations
 import json
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 NAN = float("nan")
 
@@ -54,6 +56,21 @@ def to_host(x) -> float:
     happen."""
     with jax.profiler.TraceAnnotation(SPAN_SYNC):
         return float(x)
+
+
+@jax.jit
+def _stack_scalars(xs):
+    return jnp.stack(xs)
+
+
+def to_host_all(xs: Sequence) -> List[float]:
+    """``[float(x) for x in xs]`` of device scalars as one blocking read:
+    the scalars are stacked on the device, then copied in one transfer,
+    inside one ``fsl.sync`` span."""
+    if not xs:
+        return []
+    with jax.profiler.TraceAnnotation(SPAN_SYNC):
+        return [float(v) for v in np.asarray(_stack_scalars(list(xs)))]
 
 
 @dataclass(frozen=True)
