@@ -70,6 +70,9 @@ CONTROLLERS = ("codec", "sigma", "split", "deadline")
 OBS_SINKS = ("trace", "metrics", "feedback", "alerts", "digests")
 # what a fatal health verdict does to the run (obs/health.py)
 HEALTH_POLICIES = ("record", "warn", "abort", "rollback")
+# the DCGAN generator's layout (models/dcgan.py): "mnist" is the FSL-GAN
+# paper's 28x28 G, "radford" DCGAN's published G (arXiv:1511.06434 Fig. 1)
+GEN_LAYOUTS = ("mnist", "radford")
 
 
 def _check_name(section: str, field_name: str, value: str,
@@ -152,12 +155,31 @@ class EncDecConfig:
 
 @dataclass
 class DCGANConfig:
-    """The paper's own model: DCGAN with 3 conv blocks (Radford et al. 2016)."""
+    """DCGAN (Radford et al. 2016); the defaults are the FSL-GAN paper's
+    model on 28x28x1 MNIST.
+
+    D has ``conv_blocks`` 5x5 stride-2 convs of ``base_filters`` x 1, 2, 4,
+    ... channels and a linear head.  ``gen_layout`` picks G (see
+    ``models/dcgan.py``): ``"mnist"`` projects to (image_size/4)^2 x 4f
+    and runs two transposed convs and a stride-1 conv; ``"radford"``
+    projects to 4x4 and runs log2(image_size/4) stride-2 transposed convs.
+    G's widths follow ``base_filters`` in both (ngf = ndf, as published).
+    """
     image_size: int = 28
     channels: int = 1
     latent_dim: int = 100
     base_filters: int = 64
     conv_blocks: int = 3
+    gen_layout: str = "mnist"
+
+    def __post_init__(self) -> None:
+        _check_name("model.dcgan", "gen_layout", self.gen_layout, GEN_LAYOUTS)
+        side = self.image_size // 4
+        if self.gen_layout == "radford" and (
+                self.image_size % 4 or side < 2 or side & (side - 1)):
+            raise ValueError(
+                f"model.dcgan.gen_layout='radford' needs image_size 4 x 2^n "
+                f"(n >= 1), not {self.image_size}")
 
     @property
     def enabled(self) -> bool:
@@ -295,11 +317,30 @@ class ModelConfig:
 
 
 def _dcgan_params(c: DCGANConfig) -> int:
-    # generator: project latent -> (f*4, 7, 7) then 2 deconv blocks -> image
+    """Parameters of the trees ``models/dcgan.gen_init`` and ``disc_init``
+    build: a kernel and a bias on every layer, a scale and a bias on every
+    batch norm."""
+    def conv(cin, cout, bn):
+        return 25 * cin * cout + cout + (2 * cout if bn else 0)
+
     f = c.base_filters
-    g = c.latent_dim * f * 4 * 7 * 7 + (f * 4) * (f * 2) * 25 + (f * 2) * f * 25 + f * c.channels * 25
-    # discriminator: conv_blocks convs + classifier
-    d = c.channels * f * 25 + f * f * 2 * 25 + f * 2 * f * 4 * 25 + f * 4 * 7 * 7
+    if c.gen_layout == "radford":
+        n = (c.image_size // 4).bit_length() - 1
+        widths = [f * 2 ** (n - 1 - i) for i in range(n)] + [c.channels]
+        side = 4
+    else:
+        widths = [4 * f, 2 * f, f, c.channels]
+        side = c.image_size // 4
+    proj = side * side * widths[0]
+    g = c.latent_dim * proj + proj + 2 * widths[0]
+    g += sum(conv(cin, cout, i < len(widths) - 2)
+             for i, (cin, cout) in enumerate(zip(widths, widths[1:])))
+    d, cin = 0, c.channels
+    for i in range(c.conv_blocks):
+        d += conv(cin, f * 2 ** i, i > 0)
+        cin = f * 2 ** i
+    side = -(-c.image_size // 2 ** c.conv_blocks)
+    d += side * side * cin + 1
     return int(g + d)
 
 

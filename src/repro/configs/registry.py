@@ -9,7 +9,7 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
-from repro.config import ATTN_SLIDING, INPUT_SHAPES, RunConfig
+from repro.config import ATTN_SLIDING, DCGAN, INPUT_SHAPES, RunConfig
 
 # arch id -> module name
 _ARCHS: Dict[str, str] = {
@@ -23,11 +23,18 @@ _ARCHS: Dict[str, str] = {
     "granite-20b": "repro.configs.granite_20b",
     "qwen2-72b": "repro.configs.qwen2_72b",
     "llama3-405b": "repro.configs.llama3_405b",
-    # the paper's own model
+    # the paper's own model, and DCGAN at its published 64x64 widths
     "dcgan-mnist": "repro.configs.dcgan_mnist",
+    "dcgan64": "repro.configs.dcgan64",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCHS if a != "dcgan-mnist"]
+
+def _family(arch: str) -> str:
+    return importlib.import_module(_ARCHS[arch]).config().model.family
+
+
+# the language-model archs that iter_pairs / dryrun pair with INPUT_SHAPES
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCHS if _family(a) != DCGAN]
 SHAPES: List[str] = list(INPUT_SHAPES)
 
 # long_500k handling per arch (DESIGN.md §4)

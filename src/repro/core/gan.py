@@ -98,6 +98,14 @@ def g_loss_fn(g_params, d_params, z, c) -> jnp.ndarray:
     return bce_logits(disc_apply(d_params, fake, c), 1.0)
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _gather_rows(rows: jnp.ndarray, idx, shape: Tuple[int, ...]
+                 ) -> jnp.ndarray:
+    """Rows ``idx`` of a client's images, held on the device as one
+    (N, H*W*C) matrix, as a batch of ``shape``."""
+    return rows[idx].reshape(shape)
+
+
 @dataclass
 class GANState:
     g_params: Any
@@ -151,6 +159,7 @@ class FSLGANTrainer:
             self.pool, self._layers, self.knobs.split_strategy,
             cfg.fsl.seed)
         self._rng = np.random.default_rng(seed)
+        self._rows_on_device: Dict[str, Tuple[np.ndarray, jnp.ndarray]] = {}
         self._build_steps()
         # privacy subsystem (cfg.privacy): DP-SGD inside the local step
         # (either backend — the program compiles it), the pre-codec uplink
@@ -317,9 +326,24 @@ class FSLGANTrainer:
         return self._d_step(dp, do, real, fake)
 
     def _sample_real(self, cid: str, n: int) -> jnp.ndarray:
+        """``n`` of the client's images drawn with replacement by the host
+        RNG and gathered on the device, so a round copies indices, not
+        images, to the device."""
         data = self.client_data[cid]
         idx = self._rng.integers(0, len(data), n)
-        return jnp.asarray(data[idx])
+        return _gather_rows(self._device_rows(cid), idx,
+                            (n,) + data.shape[1:])
+
+    def _device_rows(self, cid: str) -> jnp.ndarray:
+        """The client's images on the device, one flat row each, so that a
+        batch is a gather of whole rows (copied on first use, and again if
+        the client's array is replaced)."""
+        data = self.client_data[cid]
+        held = self._rows_on_device.get(cid)
+        if held is None or held[0] is not data:
+            held = (data, jnp.asarray(data.reshape(len(data), -1)))
+            self._rows_on_device[cid] = held
+        return held[1]
 
     def _z(self, n: int) -> jnp.ndarray:
         return jnp.asarray(self._rng.standard_normal(

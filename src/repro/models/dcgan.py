@@ -1,10 +1,29 @@
-"""DCGAN (Radford et al. 2016) — the paper's model: 3-conv-block
-discriminator + transposed-conv generator for 28x28x1 MNIST.
+"""DCGAN (Radford et al. 2016): a discriminator of ``conv_blocks`` 5x5
+stride-2 conv blocks and a transposed-conv generator, at any image size and
+channel count.
+
+The generator has two layouts, picked by ``DCGANConfig.gen_layout``:
+
+  * ``"mnist"``, the FSL-GAN paper's 28x28x1 G: the latent projected to
+    (image_size/4)^2 x 4f, two 5x5 stride-2 transposed convs to 2f and f,
+    then a 5x5 stride-1 conv to the image's channels;
+  * ``"radford"``, DCGAN's published G (arXiv:1511.06434, Fig. 1): the
+    latent projected to 4x4 x f*2^(n-1), then n = log2(image_size/4) 5x5
+    stride-2 transposed convs halving the channels, the last to the
+    image's channels.  At 64x64x3 and f = 128 that is 4x4x1024 -> 8x8x512
+    -> 16x16x256 -> 32x32x128 -> 64x64x3.
+
+Batch norm and ReLU follow every G layer but the last, which ends in tanh.
 
 The discriminator is the part the paper federates and splits; it is
 deliberately expressed as an ordered list of *named layers* so the FSL split
 planner (core/split.py) can cost and partition it exactly the way the paper
 partitions "portions" across a client's devices.
+
+Each G stage and each D block runs under a ``jax.named_scope``,
+``gen.<layer>`` or ``disc.<layer>`` with ``<layer>`` the key of its
+parameters, so the compiled programs' op names say which layer an op
+belongs to.
 """
 from __future__ import annotations
 
@@ -28,6 +47,12 @@ def _conv_init(key, kh, kw, cin, cout, dtype=jnp.float32):
 
 def _bn_init(c, dtype=jnp.float32):
     return {"scale": jnp.ones((c,), dtype), "bias": jnp.zeros((c,), dtype)}
+
+
+def _scope(model: str, layer: str):
+    """The named scope of one layer: ``"<model>.<layer>"``, ``model``
+    ``"gen"`` or ``"disc"`` and ``layer`` the key of its parameters."""
+    return jax.named_scope(f"{model}.{layer}")
 
 
 def _bn_apply(p, x, eps=1e-5):
@@ -74,7 +99,6 @@ def disc_init(key, c: DCGANConfig, dtype=jnp.float32) -> Dict[str, Any]:
         if i > 0:
             p[f"conv{i}"]["bn"] = _bn_init(cout, dtype)
         cin = cout
-    final_sz = c.image_size // (2 ** c.conv_blocks)
     # pad 28 -> strided convs give ceil: 28->14->7->4
     final_sz = -(-c.image_size // (2 ** c.conv_blocks))
     p["classifier"] = {
@@ -97,6 +121,11 @@ def disc_specs(c: DCGANConfig) -> Dict[str, Any]:
 
 def disc_apply_layer(name: str, p, x, c: DCGANConfig) -> jnp.ndarray:
     """Apply one named discriminator layer (the unit of an FSL portion)."""
+    with _scope("disc", name):
+        return _disc_layer(name, p, x)
+
+
+def _disc_layer(name: str, p, x) -> jnp.ndarray:
     if name.startswith("conv"):
         lp = p[name]
         y = jax.lax.conv_general_dilated(
@@ -134,6 +163,8 @@ def _deconv_init(key, kh, kw, cin, cout, dtype=jnp.float32):
 
 
 def gen_init(key, c: DCGANConfig, dtype=jnp.float32) -> Dict[str, Any]:
+    if c.gen_layout == "radford":
+        return _radford_init(key, c, dtype)
     ks = jax.random.split(key, 4)
     f = c.base_filters
     s0 = c.image_size // 4            # 7 for 28x28
@@ -153,6 +184,12 @@ def gen_init(key, c: DCGANConfig, dtype=jnp.float32) -> Dict[str, Any]:
 
 def gen_specs(c: DCGANConfig) -> Dict[str, Any]:
     bn = {"scale": Lg(None), "bias": Lg(None)}
+    if c.gen_layout == "radford":
+        *mid, last = _radford_convs(c)
+        return {"proj": {"w": Lg(None, "mlp"), "b": Lg("mlp"), "bn": bn},
+                **{name: {"w": Lg(None, None, "mlp", None), "b": Lg(None),
+                          "bn": bn} for name, _, _ in mid},
+                last[0]: {"w": Lg(None, None, None, None), "b": Lg(None)}}
     return {
         "proj": {"w": Lg(None, "mlp"), "b": Lg("mlp"), "bn": bn},
         "deconv0": {"w": Lg(None, None, "mlp", None), "b": Lg(None), "bn": bn},
@@ -170,15 +207,65 @@ def _deconv(x, lp, stride=2):
 
 def gen_apply(p, z: jnp.ndarray, c: DCGANConfig) -> jnp.ndarray:
     """z: (B, latent) -> images (B, H, W, C) in (-1, 1)."""
+    if c.gen_layout == "radford":
+        return _radford_apply(p, z, c)
     f = c.base_filters
     s0 = c.image_size // 4
     b = z.shape[0]
-    x = z @ p["proj"]["w"].astype(z.dtype) + p["proj"]["b"].astype(z.dtype)
-    x = x.reshape(b, s0, s0, f * 4)
-    x = jax.nn.relu(_bn_apply(p["proj"]["bn"], x))
-    x = jax.nn.relu(_bn_apply(p["deconv0"]["bn"], _deconv(x, p["deconv0"])))
-    x = jax.nn.relu(_bn_apply(p["deconv1"]["bn"], _deconv(x, p["deconv1"])))
-    x = jax.lax.conv_general_dilated(x, p["out"]["w"].astype(x.dtype),
-                                     (1, 1), "SAME", dimension_numbers=DN)
-    x = x + p["out"]["b"].astype(x.dtype)
-    return jnp.tanh(x)
+    with _scope("gen", "proj"):
+        x = z @ p["proj"]["w"].astype(z.dtype) + p["proj"]["b"].astype(z.dtype)
+        x = x.reshape(b, s0, s0, f * 4)
+        x = jax.nn.relu(_bn_apply(p["proj"]["bn"], x))
+    for name in ("deconv0", "deconv1"):
+        with _scope("gen", name):
+            x = jax.nn.relu(_bn_apply(p[name]["bn"], _deconv(x, p[name])))
+    with _scope("gen", "out"):
+        x = jax.lax.conv_general_dilated(x, p["out"]["w"].astype(x.dtype),
+                                         (1, 1), "SAME", dimension_numbers=DN)
+        x = x + p["out"]["b"].astype(x.dtype)
+        return jnp.tanh(x)
+
+
+# DCGAN's published G: a 4x4 projection, then stride-2 transposed convs
+# until the image's size.
+RADFORD_SIDE = 4
+
+
+def _radford_convs(c: DCGANConfig) -> List[Tuple[str, int, int]]:
+    """(name, in channels, out channels) of each transposed conv."""
+    n = (c.image_size // RADFORD_SIDE).bit_length() - 1
+    widths = [c.base_filters * 2 ** (n - 1 - i) for i in range(n)]
+    widths.append(c.channels)
+    return [(f"deconv{i}", widths[i], widths[i + 1]) for i in range(n)]
+
+
+def _radford_init(key, c: DCGANConfig, dtype) -> Dict[str, Any]:
+    convs = _radford_convs(c)
+    ks = jax.random.split(key, len(convs) + 1)
+    c0 = convs[0][1]
+    width = RADFORD_SIDE * RADFORD_SIDE * c0
+    p: Dict[str, Any] = {
+        "proj": {"w": (jax.random.normal(ks[0], (c.latent_dim, width),
+                                         jnp.float32)
+                       * c.latent_dim ** -0.5).astype(dtype),
+                 "b": jnp.zeros((width,), dtype),
+                 "bn": _bn_init(c0, dtype)}}
+    for k, (name, cin, cout) in zip(ks[1:], convs):
+        p[name] = _deconv_init(k, 5, 5, cin, cout, dtype)
+        if name != convs[-1][0]:
+            p[name]["bn"] = _bn_init(cout, dtype)
+    return p
+
+
+def _radford_apply(p, z: jnp.ndarray, c: DCGANConfig) -> jnp.ndarray:
+    convs = _radford_convs(c)
+    with _scope("gen", "proj"):
+        x = z @ p["proj"]["w"].astype(z.dtype) + p["proj"]["b"].astype(z.dtype)
+        x = x.reshape(z.shape[0], RADFORD_SIDE, RADFORD_SIDE, convs[0][1])
+        x = jax.nn.relu(_bn_apply(p["proj"]["bn"], x))
+    for name, _, _ in convs[:-1]:
+        with _scope("gen", name):
+            x = jax.nn.relu(_bn_apply(p[name]["bn"], _deconv(x, p[name])))
+    name = convs[-1][0]
+    with _scope("gen", name):
+        return jnp.tanh(_deconv(x, p[name]))
