@@ -348,8 +348,7 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
     # both fast and full mode — the regress gate's boolean rules
     # (numerics_ok, speedup_ok@64) must hold at any size, so only the
     # leaf width shrinks under fast.
-    from repro.fed.aggregate import (StreamingAggregator, batched_reduce,
-                                     decode_enc)
+    from repro.fed.aggregate import StreamingAggregator, batched_reduce
     from repro.fed.programs import fedavg_stacked, stack_trees
     from repro.fed.transport import make_codec
     from repro.roofline.analysis import agg_fuse_terms
@@ -371,6 +370,7 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
             best = min(best, time.time() - t0)
         return best * 1e6
 
+    int8 = make_codec("int8")
     for c in (4, 16, 64):
         akey = jax.random.PRNGKey(c)
         encs, wire_b = [], 0
@@ -379,19 +379,19 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
             d = {"w": 0.1 * jax.random.normal(ki, (leaf,), jnp.float32),
                  "b": 0.1 * jax.random.normal(jax.random.fold_in(ki, 1),
                                               (leaf // 4,), jnp.float32)}
-            enc, nb = make_codec("int8").encode_tree(d)
+            enc, nb = int8.encode_tree(d)
             encs.append(enc)
             wire_b += nb
         agg_w = [1.0 + (i % 3) for i in range(c)]    # non-uniform weights
 
         def _decode_reduce():
-            trees = [decode_enc("int8", e, template) for e in encs]
+            trees = [int8.decode_tree(e, template) for e in encs]
             out = fedavg_stacked(stack_trees(trees), agg_w)
             jax.block_until_ready(out)
             return out
 
         def _stream():
-            agg = StreamingAggregator("int8")
+            agg = StreamingAggregator(int8)
             agg.init(template)
             for e, w in zip(encs, agg_w):
                 agg.fold(e, w)
@@ -400,7 +400,7 @@ def run(fast: bool = False) -> List[Tuple[str, float, str]]:
             return out
 
         def _batched():
-            out = batched_reduce("int8", encs, agg_w, template)
+            out = batched_reduce(int8, encs, agg_w, template)
             jax.block_until_ready(out)
             return out
 

@@ -885,7 +885,7 @@ class FSLGANTrainer:
                                      noise_multiplier=sigma_arg)
             elif self.cfg.privacy.mode == "uplink":
                 # one release per executed uplink: every client_infos entry
-                # ran _codec_roundtrip once
+                # ran _encode_uplink once
                 self.accountant.step(len(rep.client_infos),
                                      noise_multiplier=sigma_arg)
         metrics = {

@@ -38,6 +38,7 @@ instead of plan → price.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -236,8 +237,8 @@ class CodecBoundaryStage(BoundaryStage):
         return dec
 
     def wire_bytes(self, shape: Sequence[int], dtype=jnp.float32) -> int:
-        _, nbytes = self.codec.roundtrip(jnp.zeros(tuple(shape), dtype))
-        return int(nbytes)
+        return self.codec.leaf_bytes(math.prod(shape),
+                                     jnp.dtype(dtype).itemsize)
 
 
 class GaussianBoundaryStage(BoundaryStage):
@@ -348,12 +349,9 @@ class FusedBoundaryStage(BoundaryStage):
         return y.reshape(x.shape).astype(x.dtype)
 
     def wire_bytes(self, shape: Sequence[int], dtype=jnp.float32) -> int:
-        if self.codec_name == "none":
-            return tensor_wire_bytes(shape, dtype)
         from repro.fed.transport import make_codec
-        _, nbytes = make_codec(self.codec_name).roundtrip(
-            jnp.zeros(tuple(shape), dtype))
-        return int(nbytes)
+        return make_codec(self.codec_name).leaf_bytes(
+            math.prod(shape), jnp.dtype(dtype).itemsize)
 
 
 def make_boundary_stage(split_cfg, name: Optional[str] = None

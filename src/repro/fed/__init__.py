@@ -2,7 +2,9 @@
 
   transport   links, byte accounting, compression codecs
   events      event queue + client availability traces
-  policies    FedAvg / FedAsync / FedBuff aggregation
+  aggregate   the server reduce: one interface over the decode / stream /
+              batched modes (make_server_reduce)
+  policies    FedAsync / FedBuff async aggregation
   programs    the client-side local round as data: one step definition
               (plain | DP-SGD), two compilations (loop | vectorized),
               per-client lr/steps schedules, pure round execution
@@ -15,7 +17,7 @@ from repro.fed.events import (AlwaysAvailable,  # noqa: F401
                               BernoulliAvailability, EventQueue,
                               make_availability)
 from repro.fed.policies import (AggregationPolicy, ClientUpdate,  # noqa: F401
-                                FedAsync, FedBuff, SyncFedAvg, make_policy)
+                                FedAsync, FedBuff, make_policy)
 from repro.fed.programs import (BACKENDS, CallableProgram,  # noqa: F401
                                 ClientHyper, ClientResult, LocalProgram,
                                 RoundExecutor, as_program, fedavg_stacked,

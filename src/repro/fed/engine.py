@@ -7,8 +7,8 @@ round, every available client
   2. runs local split-discriminator training  (compute, priced by the
      paper's analytic model ``core/simulate.plan_epoch_time``),
   3. uplinks its discriminator update through a compression codec
-     (``fed/transport``), and
-  4. the server aggregates per its policy     (``fed/policies``).
+     (``fed/transport``), encoded once (``_encode_uplink``), and
+  4. the server reduces the landed uplinks    (``fed/aggregate``).
 
 The engine schedules **client programs** (``fed/programs``): an object
 whose ``run(cids, start_params)`` executes the listed clients' local
@@ -32,6 +32,15 @@ Two scheduling modes:
     delay, and staleness = how many global versions advanced in between.
     Fast clients can cycle ``async_cycles`` times per round.
 
+The three round paths — flat sync, two-tier sync (``fed/hierarchy``) and
+async — run over one server reduce, a ``fed/aggregate.ServerReduce`` from
+``make_server_reduce``, and never name ``fed.server_reduce`` themselves.
+The reduce measures each executed uplink's codec error, folds the landed
+ones into the new global tree (sync; per edge cohort, then over the
+cohort aggregates, in the hierarchy) or decides what waits in flight
+(async, where the policy in ``fed/policies`` applies each arrival), and
+counts ``RoundReport.peak_live_trees``.
+
 Optimizer-state purity: executions stash their resulting opt state with
 the (virtual) arrival; only updates that actually land inside the deadline
 commit to ``RoundReport.opt_states``.  A dropped straggler therefore
@@ -44,22 +53,20 @@ whatever accelerator hosts the process, exactly like the seed.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-from repro.fed.aggregate import (StreamingAggregator, batched_reduce,
-                                 codec_rel_error, decode_enc,
-                                 fused_decode_apply)
+from repro.fed.aggregate import DecodeReduce, Uplink, make_server_reduce
 from repro.fed.events import ARRIVE, FINISH, EventQueue, make_availability
 from repro.fed.hierarchy import HierarchicalAggregator
 from repro.fed.policies import ClientUpdate, make_policy
 from repro.fed.programs import as_program
-from repro.fed.transport import (LinkModel, TrafficLedger, apply_delta,
-                                 delta_tree, make_codec, tree_bytes,
-                                 tree_rel_error)
-from repro.obs.trace import SPAN_REDUCE, SPAN_SYNC
+from repro.fed.transport import (IdentityCodec, LinkModel, TrafficLedger,
+                                 delta_tree, make_codec)
+from repro.obs.trace import SPAN_REDUCE
 
 # legacy program shape: local_update(client_id, start_params)
 #   -> (trained_params, info_dict)
@@ -128,15 +135,17 @@ class FederationEngine:
         self.roster = [s.client_id for s in specs]
         self.specs = {s.client_id: s for s in specs}
         self.weighted = bool(weighted)
-        self.policy = make_policy(fed_cfg, weighted=weighted)
-        # server reduce strategy (config.SERVER_REDUCES): "decode" stages
-        # decoded trees through the policy (bit-exact reference);
-        # "stream"/"batched" aggregate wire payloads in the compressed
-        # domain (fed/aggregate) — on the sync paths, where the reduce is
-        # a plain weighted mean.  Async always decodes per-arrival, but
-        # under "stream"/"batched" the ARRIVE queue carries wire payloads
-        # instead of decoded trees (O(1) live decoded state).
-        self.server_reduce = str(getattr(fed_cfg, "server_reduce", "decode"))
+        # async arrivals apply through a policy (fed/policies); a sync
+        # round's barrier average is its server reduce
+        self.policy = (make_policy(fed_cfg) if fed_cfg.mode != "sync"
+                       else None)
+        # the server reduce (fed/aggregate, config.SERVER_REDUCES): every
+        # round path builds its reduce here and never names the mode
+        self._reduce = functools.partial(
+            make_server_reduce,
+            str(getattr(fed_cfg, "server_reduce", "decode")),
+            use_kernel=fed_cfg.kernel_aggregation,
+            interpret=fed_cfg.kernel_interpret)
         # optional client mesh for the kernel-backed reduces: stacked
         # leaves land on the `clients` axis before the fused reduce
         self.mesh = None
@@ -166,9 +175,8 @@ class FederationEngine:
             float(getattr(fed_cfg, "edge_uplink_bps", 200e6)))
         self.hierarchy: Optional[HierarchicalAggregator] = None
         if cohorts >= 2 and fed_cfg.mode == "sync":
-            self.hierarchy = HierarchicalAggregator(
-                cohorts, use_kernel=fed_cfg.kernel_aggregation,
-                interpret=fed_cfg.kernel_interpret, cohort_of=cohort_of)
+            self.hierarchy = HierarchicalAggregator(cohorts,
+                                                    cohort_of=cohort_of)
         self.availability = make_availability(fed_cfg.availability,
                                               fed_cfg.availability_seed)
         self.clock = 0.0
@@ -207,27 +215,27 @@ class FederationEngine:
 
     def set_mesh(self, mesh) -> None:
         """Attach a client device mesh for the kernel-backed server
-        reduces ("decode" through the fedavg kernel, "batched"): per-leaf
-        client stacks are placed with ``sharding.stacked_shardings`` and
-        reduced per device (``sharding.specs.psum_client_rows``).
-        ``None`` (the default) keeps every reduce single-device.
+        reduces that have a per-device form (``ServerReduce.per_device``):
+        per-leaf client stacks are placed with
+        ``sharding.stacked_shardings`` and reduced per device
+        (``sharding.specs.psum_client_rows``).  ``None`` (the default)
+        keeps every reduce single-device.
 
-        A compiled Mosaic kernel cannot take arrays that span devices, and
-        the "stream" fold and the edge-cohort reduce have no per-device
-        form: on a multi-device mesh they are refused here rather than
-        left to fail at compile time."""
+        A compiled Mosaic kernel cannot take arrays that span devices, so
+        a reduce without a per-device form (the "stream" fold, every
+        edge-cohort reduce) is refused here on a multi-device mesh rather
+        than left to fail at compile time."""
         if (mesh is not None and mesh.size > 1
                 and self.cfg.kernel_aggregation
                 and not self.cfg.kernel_interpret
-                and (self.server_reduce == "stream"
-                     or self.hierarchy is not None)):
+                and not self._reduce(edge=self.hierarchy is not None)
+                .per_device):
             raise ValueError(
                 "fed.kernel_aggregation with server_reduce='stream' or "
                 "hierarchy_cohorts >= 2 cannot run its Pallas kernels on "
                 f"a {mesh.size}-device clients mesh; use server_reduce="
                 "'decode' or 'batched', or turn fed.shard_clients off")
         self.mesh = mesh
-        self.policy.mesh = mesh
 
     def set_tracer(self, tracer, *, batch_cap: int = 0) -> None:
         """Attach a :class:`repro.obs.Tracer`; subsequent rounds emit
@@ -247,51 +255,26 @@ class FederationEngine:
         self._digester = fn
 
     # ------------------------------------------------------------------
-    def _codec_roundtrip(self, cid: str, base_tree, params
-                         ) -> Tuple[Any, int, float]:
-        """Uplink params through the client's codec; lossy codecs compress
-        the delta vs the tree the client downloaded (``base_tree``).  An
-        ``uplink_stage`` (DP clip+noise) runs on the delta first, so lossy
-        codecs compress — and the server only decodes — the privatized
-        update.  Returns ``(decoded, wire_bytes, rel_error)`` where
-        ``rel_error`` is the measured relative L2 error the CODEC cost the
-        (possibly privatized) delta."""
-        codec = self.codecs[cid]
-        if codec.encodes_delta or self.uplink_stage is not None:
-            delta = delta_tree(params, base_tree)
-            if self.uplink_stage is not None:
-                delta = self.uplink_stage(cid, delta)
-            dec, nbytes = codec.roundtrip(delta)
-            err = 0.0
-            if codec.encodes_delta:
-                # reads both trees back to the host, leaf by leaf: one
-                # span for the call
-                with jax.profiler.TraceAnnotation(SPAN_SYNC):
-                    err = tree_rel_error(dec, delta)
-            return apply_delta(base_tree, dec), nbytes, err
-        dec, nbytes = codec.roundtrip(params)
-        return dec, nbytes, 0.0
-
     def _encode_uplink(self, cid: str, base_tree, params
-                       ) -> Tuple[Any, int, Any, bool]:
-        """Wire-level form of :meth:`_codec_roundtrip`: encode the uplink
-        WITHOUT decoding it.  Returns ``(enc, wire_bytes, delta,
-        is_delta)`` — ``enc`` is the ``Codec.encode_tree`` payload the
-        compressed-domain reduce folds, ``delta`` the raw (possibly
-        privatized) delta for error measurement (None when the codec is
-        lossless), ``is_delta`` whether the wire is in the delta domain.
-        Prices IDENTICAL bytes, applies the same ``uplink_stage``, and
-        advances stateful codec residuals exactly like the decode path."""
+                       ) -> Tuple[Uplink, Any]:
+        """The client's uplink, encoded once by its codec.  Lossy codecs
+        encode the delta vs the tree the client downloaded
+        (``base_tree``); an ``uplink_stage`` (DP clip+noise) runs on the
+        delta first, so the codec — and the server — only ever see the
+        privatized update.  Returns ``(uplink, delta)``: ``delta`` is the
+        raw (possibly privatized) delta the codec's error is measured
+        against, None when the codec is lossless.  Stateful codecs
+        advance their residual here, whether or not the update lands."""
         codec = self.codecs[cid]
         if codec.encodes_delta or self.uplink_stage is not None:
             delta = delta_tree(params, base_tree)
             if self.uplink_stage is not None:
                 delta = self.uplink_stage(cid, delta)
             enc, nbytes = codec.encode_tree(delta)
-            return (enc, nbytes,
-                    delta if codec.encodes_delta else None, True)
+            return (Uplink(codec, enc, nbytes, base_tree, True),
+                    delta if codec.encodes_delta else None)
         enc, nbytes = codec.encode_tree(params)
-        return enc, nbytes, None, False
+        return Uplink(codec, enc, nbytes, base_tree, False), None
 
     def _split_roster(self) -> Tuple[List[str], List[str]]:
         up, down = [], []
@@ -395,7 +378,7 @@ class FederationEngine:
             args: Dict[str, Any] = {}
             if cid in rep.stragglers:
                 args["dropped"] = True
-            # ran iff the codec round-tripped its update this round
+            # ran iff its uplink was encoded (and its error measured)
             if cid not in rep.codec_error:
                 args["executed"] = False   # provably-late lower bound
                 self._emit_exec_span(tr, rnd, cid, t0 + dt,
@@ -477,43 +460,21 @@ class FederationEngine:
                 runnable.append(cid)
         results = program.run(runnable, global_tree)
 
-        # the server reduce: codec round trips, then the fold or FedAvg
+        # the server reduce: each uplink encoded once, then its reduce
         with jax.profiler.TraceAnnotation(SPAN_REDUCE):
-            # compressed-domain reduce ("stream"/"batched"): landed uplinks
-            # fold as WIRE payloads — no per-client decoded tree is staged
-            reduce_mode = self.server_reduce
-            agg: Optional[StreamingAggregator] = None
-            staged: List[Tuple[Any, float]] = []      # batched: (enc, weight)
-            is_delta = False
-            if reduce_mode != "decode":
-                agg = StreamingAggregator(
-                    self.codec_name, use_kernel=self.cfg.kernel_aggregation,
-                    interpret=self.cfg.kernel_interpret)
-                agg.init(global_tree)
-
+            reduce = self._reduce(mesh=self.mesh)
             for res in results:
                 cid = res.client_id
                 spec = self.specs[cid]
-                if reduce_mode == "decode":
-                    decoded, up_b, cerr = self._codec_roundtrip(
-                        cid, global_tree, res.params)
-                else:
-                    enc, up_b, delta, is_delta = self._encode_uplink(
-                        cid, global_tree, res.params)
+                up, delta = self._encode_uplink(cid, global_tree, res.params)
                 finish = down_t[cid] + spec.compute_time_s \
-                    + self.uplink.transfer_time(up_b)
-                rep.traffic.record(cid, up=up_b, down=db(cid),
+                    + self.uplink.transfer_time(up.nbytes)
+                rep.traffic.record(cid, up=up.nbytes, down=db(cid),
                                    lan=self._lan_by.get(cid, 0))
                 rep.client_infos.append((cid, res.info))
                 rep.finish_s[cid] = finish
-                if reduce_mode == "decode":
-                    rep.codec_error[cid] = cerr
+                rep.codec_error[cid] = reduce.error(up, delta)
                 if deadline and finish > deadline:
-                    if reduce_mode != "decode":
-                        # ran but never folds: measure the codec's cost
-                        # without decoding the dropped update
-                        rep.codec_error[cid] = codec_rel_error(
-                            self.codec_name, enc, delta)
                     # ran, but its update is late: nothing commits, not
                     # even its optimizer state
                     rep.stragglers.append(cid)
@@ -524,40 +485,11 @@ class FederationEngine:
                 rep.staleness[cid] = 0
                 rep.staleness_events.append(0)
                 finishes.append(finish)
-                if reduce_mode == "stream":
-                    # fold now; the rel error rides the same traversal
-                    err = agg.fold(enc, spec.weight if self.weighted else 1.0,
-                                   delta=delta)
-                    rep.codec_error[cid] = 0.0 if err is None else err
-                elif reduce_mode == "batched":
-                    staged.append((enc, spec.weight if self.weighted else 1.0))
-                    rep.codec_error[cid] = codec_rel_error(
-                        self.codec_name, enc, delta)
-                else:
-                    self.policy.on_update(
-                        global_tree, ClientUpdate(cid, decoded, spec.weight,
-                                                  0, self.clock + finish))
-
-            if reduce_mode == "decode":
-                new_global = self.policy.on_round_end(global_tree)
-                rep.peak_live_trees = len(rep.participated)
-            else:
-                if reduce_mode == "stream":
-                    mean = agg.finalize()
-                elif staged:
-                    mean = batched_reduce(
-                        self.codec_name, [e for e, _ in staged],
-                        [w for _, w in staged], global_tree,
-                        use_kernel=self.cfg.kernel_aggregation,
-                        interpret=self.cfg.kernel_interpret, mesh=self.mesh)
-                else:
-                    mean = None
-                if mean is None:
-                    new_global = global_tree
-                else:
-                    new_global = apply_delta(global_tree, mean) if is_delta \
-                        else mean
-                rep.peak_live_trees = 1 if rep.participated else 0
+                reduce.add(up, spec.weight if self.weighted else 1.0)
+            new_global = reduce.result()
+            if new_global is None:
+                new_global = global_tree
+            rep.peak_live_trees = reduce.peak_live_trees
         if rep.participated:
             self.version += 1
         # the sync barrier releases at the slowest survivor — or at the
@@ -578,9 +510,10 @@ class FederationEngine:
         """Sync round through the two-tier edge hierarchy.
 
         Client updates travel the cheap edge link (``edge_bytes``); each
-        cohort's edge pre-reduces its members' decoded updates with the
-        same weighted FedAvg the server applies, and only ONE aggregate
-        per cohort crosses the WAN (``up_bytes`` keyed ``cohort<k>``).
+        cohort's edge pre-reduces its members' uplinks through the edge
+        form of the server reduce, and only ONE full aggregate tree per
+        cohort crosses the WAN (``up_bytes`` keyed ``cohort<k>``), where
+        the server averages them.
         Weighted-mean-of-weighted-means equals the flat aggregate up to
         float reassociation, so this path pins against :meth:`_run_sync`
         at tolerance, never bit-exact.  A cohort barrier releases at its
@@ -604,34 +537,22 @@ class FederationEngine:
                 runnable.append(cid)
         results = program.run(runnable, global_tree)
 
-        # per-client: codec over the EDGE hop, deadline at edge arrival.
-        # Under the compressed-domain reduce the edge tier stages WIRE
-        # payloads, not decoded member trees — each cohort folds them
-        # through one streaming accumulator (hierarchy.
-        # reduce_all_streaming), so live decoded state at the edge is
-        # O(1) in the cohort size.
-        reduce_mode = self.server_reduce
-        is_delta = False
-        landed: Dict[str, Tuple[Any, float]] = {}   # cid -> (payload, w)
+        # per-client: codec over the EDGE hop, deadline at edge arrival
+        edge = self._reduce(edge=True)
+        landed: Dict[str, Tuple[Uplink, float]] = {}
         edge_finish: Dict[str, float] = {}
         for res in results:
             cid = res.client_id
             spec = self.specs[cid]
-            if reduce_mode == "decode":
-                payload, up_b, cerr = self._codec_roundtrip(
-                    cid, global_tree, res.params)
-            else:
-                payload, up_b, delta, is_delta = self._encode_uplink(
-                    cid, global_tree, res.params)
-                cerr = codec_rel_error(self.codec_name, payload, delta)
+            up, delta = self._encode_uplink(cid, global_tree, res.params)
             finish = down_t[cid] + spec.compute_time_s \
-                + self.edge_link.transfer_time(up_b)
+                + self.edge_link.transfer_time(up.nbytes)
             rep.traffic.record(cid, down=db(cid),
                                lan=self._lan_by.get(cid, 0))
-            rep.traffic.record_edge(cid, up_b)
+            rep.traffic.record_edge(cid, up.nbytes)
             rep.client_infos.append((cid, res.info))
             rep.finish_s[cid] = finish
-            rep.codec_error[cid] = cerr
+            rep.codec_error[cid] = edge.error(up, delta)
             if deadline and finish > deadline:
                 rep.stragglers.append(cid)
                 continue
@@ -640,44 +561,33 @@ class FederationEngine:
                 rep.opt_states[cid] = res.opt_state
             rep.staleness[cid] = 0
             rep.staleness_events.append(0)
-            landed[cid] = (payload, spec.weight)
+            landed[cid] = (up, spec.weight)
             edge_finish[cid] = finish
 
-        # per-cohort: edge pre-reduce, then ONE WAN uplink per cohort
-        if reduce_mode == "decode":
-            reductions = self.hierarchy.reduce_all(landed)
-        else:
-            reductions = self.hierarchy.reduce_all_streaming(
-                landed, global_tree, codec_name=self.codec_name)
+        # per-cohort: edge pre-reduce, then ONE WAN uplink per cohort,
+        # which carries the cohort's full aggregate tree as it is
+        reductions = self.hierarchy.reduce_all(landed, edge)
+        top = DecodeReduce(use_kernel=self.cfg.kernel_aggregation,
+                           interpret=self.cfg.kernel_interpret,
+                           mesh=self.mesh)
         cohort_finishes: List[float] = []
         cohort_trace: List[Dict[str, Any]] = []
         for red in reductions:
-            aggregate = red.aggregate
-            if reduce_mode != "decode" and is_delta:
-                # stream reduce yields the cohort's mean DELTA; rebase it
-                # so the WAN payload and the server update are the same
-                # full tree the decode path ships
-                aggregate = apply_delta(global_tree, aggregate)
-            wan_b = tree_bytes(aggregate)
+            wan = Uplink.plain(IdentityCodec(), red.aggregate)
             ready = max(edge_finish[m] for m in red.members)
-            finish = ready + self.uplink.transfer_time(wan_b)
+            finish = ready + self.uplink.transfer_time(wan.nbytes)
             ckey = f"cohort{red.cohort}"
-            rep.traffic.record(ckey, up=wan_b)
+            rep.traffic.record(ckey, up=wan.nbytes)
             cohort_finishes.append(finish)
             cohort_trace.append({"cohort": red.cohort, "ready": ready,
-                                 "finish": finish, "bytes": wan_b,
+                                 "finish": finish, "bytes": wan.nbytes,
                                  "members": list(red.members)})
-            self.policy.on_update(
-                global_tree, ClientUpdate(ckey, aggregate, red.weight,
-                                          0, self.clock + finish))
-        # decode: every landed member tree + the buffered cohort
-        # aggregates are live at once; stream: cohort aggregates + ONE
-        # accumulator, independent of cohort size
-        rep.peak_live_trees = len(landed) + len(reductions) \
-            if reduce_mode == "decode" else \
-            (len(reductions) + 1 if reductions else 0)
+            top.add(wan, red.weight if self.weighted else 1.0)
+        rep.peak_live_trees = edge.peak_live_trees + top.peak_live_trees
 
-        new_global = self.policy.on_round_end(global_tree)
+        new_global = top.result()
+        if new_global is None:
+            new_global = global_tree
         if rep.participated:
             self.version += 1
         rep.round_time_s = max(cohort_finishes) if cohort_finishes else 0.0
@@ -764,13 +674,9 @@ class FederationEngine:
                             "t1": t0 + down_t[cid], "bytes": db(cid),
                             "cycle": 1})
 
-        # under the compressed-domain reduce, in-flight ARRIVE payloads
-        # carry WIRE encodings; the decode happens per-arrival (one
-        # fused decode+rebase traversal), so live decoded trees stay at
-        # 1 no matter how many uplinks are in flight
-        stream = self.server_reduce != "decode"
-        live_payloads = 0
-        peak_payloads = 0
+        # the reduce decides what waits in flight: a decoded tree, or the
+        # wire, decoded when it lands; the policy applies each arrival
+        reduce = self._reduce()
         last_t = t0
         while queue:
             ev = queue.pop()
@@ -780,31 +686,19 @@ class FederationEngine:
             if ev.kind == FINISH:
                 snap_tree, snap_ver = snapshots[cid]
                 res = program.run([cid], snap_tree)[0]
-                if stream:
-                    enc, up_b, delta, is_delta = self._encode_uplink(
-                        cid, snap_tree, res.params)
-                    cerr = codec_rel_error(self.codec_name, enc, delta)
-                    # the snapshot rides along: it is what the uplink's
-                    # delta rebases onto, and snapshots[cid] may advance
-                    # before this arrival is processed
-                    payload = {"enc": enc, "is_delta": is_delta,
-                               "snap_tree": snap_tree}
-                else:
-                    decoded, up_b, cerr = self._codec_roundtrip(
-                        cid, snap_tree, res.params)
-                    payload = {"decoded": decoded}
-                    live_payloads += 1
-                    peak_payloads = max(peak_payloads, live_payloads)
-                rep.traffic.record(cid, up=up_b,
+                # the uplink keeps the snapshot it rebases onto:
+                # snapshots[cid] may advance before this arrival lands
+                up, delta = self._encode_uplink(cid, snap_tree, res.params)
+                rep.traffic.record(cid, up=up.nbytes,
                                    lan=self._lan_by.get(cid, 0))
                 rep.client_infos.append((cid, res.info))
-                rep.codec_error[cid] = cerr
+                rep.codec_error[cid] = reduce.error(up, delta)
                 # the opt state rides with the arrival: it only commits if
                 # the update actually lands inside the deadline
-                up_t = self.uplink.transfer_time(up_b)
-                payload.update({"snap_ver": snap_ver,
-                                "cycle": ev.payload["cycle"],
-                                "opt_state": res.opt_state})
+                up_t = self.uplink.transfer_time(up.nbytes)
+                payload = {"update": reduce.stage(up), "snap_ver": snap_ver,
+                           "cycle": ev.payload["cycle"],
+                           "opt_state": res.opt_state}
                 queue.push(ev.time + up_t, ARRIVE, cid, payload=payload)
                 if self.tracer is not None:
                     tev.append({"kind": "exec", "cid": cid,
@@ -812,13 +706,12 @@ class FederationEngine:
                                 "t1": ev.time,
                                 "cycle": ev.payload["cycle"]})
                     tev.append({"kind": "up", "cid": cid, "t0": ev.time,
-                                "t1": ev.time + up_t, "bytes": up_b})
+                                "t1": ev.time + up_t, "bytes": up.nbytes})
                 continue
             # ARRIVE
-            if not stream:
-                live_payloads -= 1
             rep.finish_s[cid] = ev.time - t0      # last arrival per client
             if deadline and ev.time - t0 > deadline:
+                reduce.drop(ev.payload["update"])
                 rep.stragglers.append(cid)
                 if self.tracer is not None:
                     tev.append({"kind": "arrive", "cid": cid, "t": ev.time,
@@ -832,21 +725,10 @@ class FederationEngine:
                             "staleness": staleness, "landed": True})
             rep.staleness[cid] = staleness
             rep.staleness_events.append(staleness)
-            if stream:
-                if ev.payload["is_delta"]:
-                    update_tree = fused_decode_apply(
-                        self.codec_name, ev.payload["snap_tree"],
-                        ev.payload["enc"])
-                else:
-                    update_tree = decode_enc(self.codec_name,
-                                             ev.payload["enc"],
-                                             ev.payload["snap_tree"])
-            else:
-                update_tree = ev.payload["decoded"]
             global_tree, bumped = self.policy.on_update(
                 global_tree,
-                ClientUpdate(cid, update_tree, spec.weight,
-                             staleness, ev.time))
+                ClientUpdate(cid, reduce.land(ev.payload["update"]),
+                             spec.weight, staleness, ev.time))
             if bumped:
                 self.version += 1
             if cid not in rep.participated:
@@ -866,8 +748,7 @@ class FederationEngine:
 
         global_tree = self.policy.on_round_end(global_tree)
         self.version += 1 if rep.participated else 0
-        rep.peak_live_trees = (1 if rep.client_infos else 0) if stream \
-            else peak_payloads
+        rep.peak_live_trees = reduce.peak_live_trees
         rep.round_time_s = last_t - t0
         self.clock = last_t
         rep.clock_s = self.clock

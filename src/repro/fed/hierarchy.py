@@ -3,14 +3,14 @@
 Flat FedAvg uplinks every client's update across the WAN — per-round WAN
 bytes grow linearly in the participant count.  The two-tier topology
 interposes edge aggregators: each cohort of clients uplinks over a cheap
-LAN/MAN hop to its edge, the edge pre-reduces the cohort's updates with the
-same weighted mean the server would apply (routed through the fedavg Pallas
-kernel when ``fed.kernel_aggregation`` is on), and only ONE tree per cohort
-crosses the WAN.  WAN bytes drop by the cohort fan-in factor, and — because
-FedAvg is a weighted mean — the weighted-mean-of-weighted-means with cohort
-weights equal to the member weight sums reproduces the flat aggregate
-exactly (up to float reassociation, which is why the engine pin is
-tolerance-based, not bit-exact).
+LAN/MAN hop to its edge, the edge pre-reduces the cohort's uplinks through
+the edge form of the server reduce (``fed/aggregate.make_server_reduce``
+with ``edge=True``), and only ONE tree per cohort crosses the WAN.  WAN
+bytes drop by the cohort fan-in factor, and — because FedAvg is a weighted
+mean — the weighted-mean-of-weighted-means with cohort weights equal to
+the member weight sums reproduces the flat aggregate exactly (up to float
+reassociation, which is why the engine pin is tolerance-based, not
+bit-exact).
 
 The engine stays the single owner of virtual time and byte accounting;
 this module only knows how to group clients and reduce a cohort.
@@ -19,8 +19,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
-
-from repro.fed.programs import fedavg_stacked, stack_trees
 
 __all__ = ["CohortReduction", "HierarchicalAggregator", "assign_cohorts"]
 
@@ -56,75 +54,34 @@ class CohortReduction:
 
 
 class HierarchicalAggregator:
-    """Edge-tier reducer: weighted FedAvg over each cohort's updates.
+    """Edge tier: groups a round's landed clients into cohorts and runs
+    each cohort through one edge reduce."""
 
-    ``use_kernel``/``interpret`` mirror the engine's aggregation-policy
-    knobs so the edge reduce exercises the same fedavg Pallas kernel as the
-    server-side reduce it replaces."""
-
-    def __init__(self, num_cohorts: int, *, use_kernel: bool = False,
-                 interpret: bool = False, cohort_of=None):
+    def __init__(self, num_cohorts: int, *, cohort_of=None):
         if num_cohorts < 1:
             raise ValueError(f"num_cohorts must be >= 1, got {num_cohorts}")
         self.num_cohorts = int(num_cohorts)
-        self.use_kernel = bool(use_kernel)
-        self.interpret = bool(interpret)
         self.cohort_of = cohort_of
 
     def group(self, client_ids: Sequence[str]) -> Dict[int, List[str]]:
         return assign_cohorts(client_ids, self.num_cohorts, self.cohort_of)
 
-    def reduce_cohort(self, cohort: int, members: Sequence[str],
-                      trees: Sequence, weights: Sequence[float]
-                      ) -> CohortReduction:
-        """Pre-reduce one cohort: weighted mean of its members' trees,
-        weight = sum of member weights (so the server's cohort-level
-        weighted mean equals the flat client-level one)."""
-        if not trees:
-            raise ValueError(f"cohort {cohort} has no member updates")
-        agg = fedavg_stacked(stack_trees(list(trees)), list(weights),
-                             use_kernel=self.use_kernel,
-                             interpret=self.interpret)
-        return CohortReduction(int(cohort), agg, float(sum(weights)),
-                               tuple(members))
-
-    def reduce_all(self, updates: Dict[str, Tuple[object, float]]
+    def reduce_all(self, updates: Dict[str, Tuple[object, float]], reduce
                    ) -> List[CohortReduction]:
-        """Reduce a full round: ``updates`` maps client id ->
-        (tree, weight); returns one reduction per non-empty cohort, in
-        cohort order."""
+        """Reduce a full round: ``updates`` maps client id -> (uplink,
+        weight); ``reduce`` is a ``fed/aggregate.ServerReduce`` (the edge
+        form), which takes the cohorts one after another.  Returns one
+        reduction per non-empty cohort, in cohort order: the cohort's
+        weighted mean as a full tree, weighted by the sum of its member
+        weights (so the server's cohort-level weighted mean equals the
+        flat client-level one)."""
         grouped = self.group(list(updates.keys()))
         out: List[CohortReduction] = []
         for c in sorted(grouped):
             members = grouped[c]
-            trees = [updates[m][0] for m in members]
-            weights = [updates[m][1] for m in members]
-            out.append(self.reduce_cohort(c, members, trees, weights))
-        return out
-
-    def reduce_all_streaming(self, updates: Dict[str, Tuple[object, float]],
-                             template, *, codec_name: str
-                             ) -> List[CohortReduction]:
-        """Compressed-domain round reduce: ``updates`` maps client id ->
-        (encoded wire payload from ``Codec.encode_tree``, weight).  Each
-        cohort folds its members' WIRE payloads through one
-        :class:`repro.fed.aggregate.StreamingAggregator` — the edge tier
-        never stacks decoded member trees; live decoded state per cohort
-        is the single fp32 accumulator.  ``aggregate`` is the cohort's
-        weighted MEAN in the wire's domain (the delta domain for lossy
-        codecs — the engine rebases onto the global tree)."""
-        from repro.fed.aggregate import StreamingAggregator
-        grouped = self.group(list(updates.keys()))
-        out: List[CohortReduction] = []
-        for c in sorted(grouped):
-            members = grouped[c]
-            agg = StreamingAggregator(codec_name,
-                                      use_kernel=self.use_kernel,
-                                      interpret=self.interpret)
-            agg.init(template)
             for m in members:
-                enc, w = updates[m]
-                agg.fold(enc, w)
-            out.append(CohortReduction(int(c), agg.finalize(),
-                                       float(agg.wsum), tuple(members)))
+                reduce.add(*updates[m])
+            out.append(CohortReduction(
+                int(c), reduce.result(),
+                float(sum(updates[m][1] for m in members)), tuple(members)))
         return out

@@ -1,8 +1,9 @@
-"""Pluggable server aggregation policies.
+"""Pluggable async aggregation policies.
 
-  SyncFedAvg   the paper's rule: barrier on all surviving clients, weighted
-               average (delegates to ``core/fedavg`` — or to the fedavg
-               Pallas kernel when ``use_kernel`` is set).
+A sync round (the paper's rule: barrier on all surviving clients, weighted
+average) has no policy: its average is the server reduce
+(``fed/aggregate.make_server_reduce``).  The async modes apply arrivals:
+
   FedAsync     Xie et al.: apply every update the moment it arrives,
                down-weighted by staleness
                    global <- (1 - a_t) * global + a_t * params,
@@ -10,18 +11,18 @@
   FedBuff      Nguyen et al.: buffer K updates (staleness-discounted
                weights), aggregate the buffer, mix with server_lr.
 
-The engine calls ``on_update`` for every arriving update (in virtual-time
-order) and ``on_round_end`` once per round; a policy returns the possibly
-updated global tree plus whether it advanced the global model version
-(which is what staleness counts).  Policies are orthogonal to how the
+The async engine calls ``on_update`` for every arriving update (in
+virtual-time order) and ``on_round_end`` once per round; a policy returns
+the possibly updated global tree plus whether it advanced the global model
+version (which is what staleness counts).  Policies are orthogonal to how the
 client side was compiled (fed/programs.py backends): an update's params
 look the same whether the local round ran as a per-client loop or inside
 the batched vmap program.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Any, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +57,6 @@ def _mix(global_tree, update_tree, rate: float):
 class AggregationPolicy:
     """Base: buffer everything, do nothing until told."""
     name = "base"
-    mesh = None         # client device mesh (FederationEngine.set_mesh)
 
     def on_update(self, global_tree, up: ClientUpdate
                   ) -> Tuple[Any, bool]:
@@ -67,42 +67,6 @@ class AggregationPolicy:
 
     def reset(self) -> None:
         pass
-
-
-class SyncFedAvg(AggregationPolicy):
-    """Barrier aggregation — the seed trainer's exact rule.
-
-    Updates are buffered in arrival order (== participation order under the
-    sync engine), and the round-end average calls the same host ``fedavg``
-    with the same ordering and weights as the seed loop, so the no-dropout
-    sync path is bit-for-bit identical.  ``use_kernel`` swaps in the Pallas
-    streaming-average kernel for the aggregation hot path.
-    """
-    name = "sync"
-
-    def __init__(self, weighted: bool = True, use_kernel: bool = False,
-                 interpret: bool = False):
-        self.weighted = weighted
-        self.use_kernel = use_kernel
-        self.interpret = interpret
-        self._buffer: List[ClientUpdate] = []
-
-    def on_update(self, global_tree, up: ClientUpdate) -> Tuple[Any, bool]:
-        self._buffer.append(up)
-        return global_tree, False
-
-    def on_round_end(self, global_tree) -> Any:
-        if not self._buffer:
-            return global_tree
-        trees = [u.params for u in self._buffer]
-        weights = ([u.weight for u in self._buffer] if self.weighted
-                   else None)
-        self._buffer = []
-        if self.use_kernel:
-            from repro.kernels.fedavg.ops import fedavg_trees
-            return fedavg_trees(trees, weights, interpret=self.interpret,
-                                mesh=self.mesh)
-        return _fedavg(trees, weights)
 
 
 class FedAsync(AggregationPolicy):
@@ -159,11 +123,8 @@ class FedBuff(AggregationPolicy):
         self._buffer = []
 
 
-def make_policy(fed_cfg, *, weighted: bool = True) -> AggregationPolicy:
-    """Factory keyed by ``config.FedConfig.mode``."""
-    if fed_cfg.mode == "sync":
-        return SyncFedAvg(weighted, fed_cfg.kernel_aggregation,
-                          fed_cfg.kernel_interpret)
+def make_policy(fed_cfg) -> AggregationPolicy:
+    """Factory keyed by ``config.FedConfig.mode`` (the async modes)."""
     if fed_cfg.mode == "fedasync":
         return FedAsync(fed_cfg.fedasync_alpha, fed_cfg.staleness_power)
     if fed_cfg.mode == "fedbuff":

@@ -13,7 +13,10 @@ communication cost and the privacy surface, so the runtime makes it
 first-class: every transfer is priced by a :class:`LinkModel` and counted in
 a :class:`TrafficLedger`; uplink trees can be run through pluggable
 compression codecs (fp16 / int8 quantize-dequantize / top-k sparsification
-with error feedback).
+with error feedback).  Each codec defines its wire format once, per tensor
+(``encode`` / ``decode`` / ``leaf_bytes``); the base :class:`Codec` builds
+the tree forms — ``encode_tree``, ``decode_tree`` and ``roundtrip`` — from
+those, and the server reduce (``fed/aggregate``) reads wires through them.
 
 LAN hops *inside* one client's split chain are a third budget: when
 training executes through the split (``core/split.SplitExecution``), the
@@ -31,6 +34,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+EncTree = List[Tuple[Any, Any]]      # per-leaf (wire, meta), leaves order
 
 
 def tree_bytes(tree) -> int:
@@ -75,20 +81,12 @@ def tree_rel_error(approx, exact) -> float:
 
 def predict_codec_bytes(name: str, leaf_sizes: Sequence[int], *,
                         dtype_bytes: int = 4, topk_frac: float = 0.01) -> int:
-    """Analytic wire bytes of one uplink round-trip per codec — a pure
-    function of the tree's leaf sizes, so the codec controller can rank
-    candidates by cost WITHOUT spending a probe round on each (only the
-    delta ERROR needs live measurement)."""
-    if name in ("none", "", "identity"):
-        return int(sum(leaf_sizes) * dtype_bytes)
-    if name == "fp16":
-        return int(sum(leaf_sizes) * 2)
-    if name == "int8":
-        return int(sum(n + 4 for n in leaf_sizes))
-    if name == "topk":
-        return int(sum(8 * min(n, max(1, int(math.ceil(topk_frac * n))))
-                       for n in leaf_sizes))
-    raise ValueError(f"unknown codec {name!r}")
+    """Analytic wire bytes of one uplink per codec — a pure function of
+    the tree's leaf sizes, so the codec controller can rank candidates by
+    cost WITHOUT spending a probe round on each (only the delta ERROR
+    needs live measurement)."""
+    codec = make_codec(name, topk_frac=topk_frac)
+    return int(sum(codec.leaf_bytes(n, dtype_bytes) for n in leaf_sizes))
 
 
 def fake_batch_bytes(batch: int, image_shape: Tuple[int, ...],
@@ -178,50 +176,68 @@ class TrafficLedger:
 # ---------------------------------------------------------------------------
 
 class Codec:
-    """Lossy round-trip over an uplink tree.
+    """One uplink wire format, defined once per tensor.
+
+    A codec writes down its format in three places and nowhere else:
+    ``encode(x) -> (wire, meta)``, ``decode(wire, meta, dtype)`` and
+    ``leaf_bytes`` (the priced wire bytes of one tensor).  The tree forms
+    are built here from those: ``encode_tree``, ``decode_tree`` and
+    ``roundtrip``.  ``encode``/``decode`` are jit-compatible pure
+    functions of the tensor, which is how the split executor's boundary
+    stages run them inside a training step.
 
     ``encodes_delta`` controls what the engine feeds it: raw parameters
     (identity — keeps the sync path bit-exact) or the update delta
     ``params - global`` (all lossy codecs: compressing deltas is the
     standard trick — they are near-zero-mean and tolerate quantization).
+    ``sparse`` marks a wire of kept ``(values, flat indices)`` rather than
+    a dense buffer: the server scatters it instead of dequantizing it
+    (``fed/aggregate``).
 
-    ``roundtrip(tree)`` returns ``(decoded_tree, wire_bytes)``.  Stateful
-    codecs (top-k with error feedback) carry a residual across calls, so the
-    engine keeps ONE codec instance PER CLIENT.
-
-    ``encode(x)`` / ``decode(wire, meta, dtype)`` are the per-tensor
-    *buffer* entry points the pipelined split executor ships hops with:
-    ``encode`` returns the actual wire arrays (the quantized buffer plus
-    whatever side metadata decoding needs) instead of a decoded
-    round-trip, and ``decode(*encode(x), x.dtype) == roundtrip(x)[0]``
-    leaf-wise for every stateless codec (pinned in tests).  Both are
-    jit-compatible pure functions of the input tensor.
+    Stateful codecs (top-k with error feedback) carry a residual across
+    ``encode_tree`` calls, so the engine keeps ONE codec instance PER
+    CLIENT.
     """
     name = "none"
     encodes_delta = False
-
-    def roundtrip(self, tree) -> Tuple[Any, int]:
-        raise NotImplementedError
+    sparse = False
 
     def encode(self, x: jnp.ndarray) -> Tuple[Any, Any]:
         """One tensor -> (wire buffer(s), decode metadata)."""
-        return x, None
+        raise NotImplementedError
 
     def decode(self, wire, meta, dtype=jnp.float32) -> jnp.ndarray:
-        """Inverse of ``encode``: reconstruct what the receiver computes
-        on (identical to the ``roundtrip`` decode for this tensor)."""
-        del meta
-        return wire.astype(dtype)
+        """Inverse of ``encode``: what the receiver computes on."""
+        raise NotImplementedError
 
-    def encode_tree(self, tree) -> Tuple[List[Tuple[Any, Any]], int]:
-        """Whole-tree wire form: per-leaf ``(wire, meta)`` in
-        ``jax.tree.leaves`` order plus the priced wire bytes — exactly
-        the bytes ``roundtrip`` reports, so the engine's byte accounting
-        is identical whichever reduce consumes the uplink.  Stateful
-        codecs (top-k error feedback) advance their residual here just
-        like ``roundtrip`` does.  The compressed-domain server reduce
-        (``fed/aggregate``) folds these payloads without decoding."""
-        return [(l, None) for l in jax.tree.leaves(tree)], tree_bytes(tree)
+    def leaf_bytes(self, size: int, itemsize: int = 4) -> int:
+        """Wire bytes of one tensor of ``size`` elements."""
+        raise NotImplementedError
+
+    def dequant_scale(self, meta):
+        """Scale of a dense wire: ``decode(wire, meta) == wire * scale``."""
+        del meta
+        return 1.0
+
+    def encode_tree(self, tree) -> Tuple[EncTree, int]:
+        """Per-leaf ``(wire, meta)`` in ``jax.tree.leaves`` order plus the
+        priced wire bytes."""
+        leaves = jax.tree.leaves(tree)
+        return ([self.encode(l) for l in leaves],
+                int(sum(self.leaf_bytes(l.size, jnp.dtype(l.dtype).itemsize)
+                        for l in leaves)))
+
+    def decode_tree(self, enc: EncTree, template, dtype=None):
+        """Decode an ``encode_tree`` payload into ``template``'s structure;
+        each leaf is cast to ``dtype`` (default: the template leaf's)."""
+        leaves = [self.decode(w, m, t.dtype if dtype is None else dtype)
+                  for (w, m), t in zip(enc, jax.tree.leaves(template))]
+        return jax.tree.unflatten(jax.tree.structure(template), leaves)
+
+    def roundtrip(self, tree) -> Tuple[Any, int]:
+        """``(decoded_tree, wire_bytes)`` of one trip over the wire."""
+        enc, nbytes = self.encode_tree(tree)
+        return self.decode_tree(enc, tree), nbytes
 
 
 class IdentityCodec(Codec):
@@ -229,20 +245,22 @@ class IdentityCodec(Codec):
     name = "none"
     encodes_delta = False
 
-    def roundtrip(self, tree) -> Tuple[Any, int]:
-        return tree, tree_bytes(tree)
+    def encode(self, x: jnp.ndarray) -> Tuple[Any, Any]:
+        return x, None
+
+    def decode(self, wire, meta, dtype=jnp.float32) -> jnp.ndarray:
+        # the leaf itself: no cast and no device op on the lossless path
+        del meta, dtype
+        return wire
+
+    def leaf_bytes(self, size: int, itemsize: int = 4) -> int:
+        return size * itemsize
 
 
 class FP16Codec(Codec):
     """Cast leaves to fp16 on the wire, back to native dtype on arrival."""
     name = "fp16"
     encodes_delta = True
-
-    def roundtrip(self, tree) -> Tuple[Any, int]:
-        dec = jax.tree.map(
-            lambda l: l.astype(jnp.float16).astype(l.dtype), tree)
-        nbytes = sum(l.size * 2 for l in jax.tree.leaves(tree))
-        return dec, int(nbytes)
 
     def encode(self, x: jnp.ndarray) -> Tuple[Any, Any]:
         return x.astype(jnp.float16), None
@@ -251,10 +269,8 @@ class FP16Codec(Codec):
         del meta
         return wire.astype(dtype)
 
-    def encode_tree(self, tree) -> Tuple[List[Tuple[Any, Any]], int]:
-        leaves = jax.tree.leaves(tree)
-        return ([(l.astype(jnp.float16), None) for l in leaves],
-                int(sum(l.size * 2 for l in leaves)))
+    def leaf_bytes(self, size: int, itemsize: int = 4) -> int:
+        return size * 2
 
 
 class Int8Codec(Codec):
@@ -265,37 +281,24 @@ class Int8Codec(Codec):
     name = "int8"
     encodes_delta = True
 
-    def roundtrip(self, tree) -> Tuple[Any, int]:
-        def qdq(l):
-            x = l.astype(jnp.float32)
-            amax = jnp.max(jnp.abs(x))
-            # zero-range (all-constant-zero) delta: any positive scale maps
-            # q=0 back to exact zeros; 1.0 avoids the subnormal division a
-            # tiny epsilon scale would do
-            scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-            q = jnp.clip(jnp.round(x / scale), -127, 127)
-            return (q * scale).astype(l.dtype)
-
-        dec = jax.tree.map(qdq, tree)
-        nbytes = sum(l.size + 4 for l in jax.tree.leaves(tree))
-        return dec, int(nbytes)
-
     def encode(self, x: jnp.ndarray) -> Tuple[Any, Any]:
         f = x.astype(jnp.float32)
         amax = jnp.max(jnp.abs(f))
+        # zero-range (all-constant-zero) delta: any positive scale maps
+        # q=0 back to exact zeros; 1.0 avoids the subnormal division a
+        # tiny epsilon scale would do
         scale = jnp.where(amax > 0, amax / 127.0, 1.0)
         q = jnp.clip(jnp.round(f / scale), -127, 127).astype(jnp.int8)
         return q, scale
 
     def decode(self, wire, meta, dtype=jnp.float32) -> jnp.ndarray:
-        # int8 buffer * fp32 scale, matching roundtrip's q * scale in
-        # fp32 before the final cast
         return (wire.astype(jnp.float32) * meta).astype(dtype)
 
-    def encode_tree(self, tree) -> Tuple[List[Tuple[Any, Any]], int]:
-        leaves = jax.tree.leaves(tree)
-        return ([self.encode(l) for l in leaves],
-                int(sum(l.size + 4 for l in leaves)))
+    def dequant_scale(self, meta):
+        return meta
+
+    def leaf_bytes(self, size: int, itemsize: int = 4) -> int:
+        return size + 4
 
 
 class TopKCodec(Codec):
@@ -308,78 +311,48 @@ class TopKCodec(Codec):
     """
     name = "topk"
     encodes_delta = True
+    sparse = True
 
     def __init__(self, frac: float = 0.01, error_feedback: bool = True):
         self.frac = float(frac)
         self.error_feedback = bool(error_feedback)
         self._residual: Optional[Any] = None
 
-    def roundtrip(self, tree) -> Tuple[Any, int]:
-        if self.error_feedback and self._residual is not None:
-            tree = jax.tree.map(lambda l, r: l + r.astype(l.dtype),
-                                tree, self._residual)
-
-        kept_entries = 0
-
-        def sparsify(l):
-            nonlocal kept_entries
-            flat = l.astype(jnp.float32).reshape(-1)
-            # clamp k into [1, n]: frac >= 1 (or tiny leaves) means keep
-            # everything — lax.top_k raises on k > n
-            k = min(flat.size, max(1, int(math.ceil(self.frac * flat.size))))
-            kept_entries += k
-            _, idx = jax.lax.top_k(jnp.abs(flat), k)
-            mask = jnp.zeros_like(flat).at[idx].set(1.0)
-            return (flat * mask).reshape(l.shape).astype(l.dtype)
-
-        dec = jax.tree.map(sparsify, tree)
-        if self.error_feedback:
-            self._residual = jax.tree.map(
-                lambda l, d: l.astype(jnp.float32) - d.astype(jnp.float32),
-                tree, dec)
-        return dec, int(kept_entries * 8)
+    def _k(self, n: int) -> int:
+        # clamp k into [1, n]: frac >= 1 (or tiny leaves) means keep
+        # everything — lax.top_k raises on k > n
+        return min(n, max(1, int(math.ceil(self.frac * n))))
 
     def encode(self, x: jnp.ndarray) -> Tuple[Any, Any]:
-        """Stateless (no error feedback) per-tensor buffer encode: the
-        kept values + their flat indices — exactly the 8-bytes-per-entry
-        wire payload ``roundtrip`` prices."""
+        """The kept values + their flat indices; ``meta`` is the shape."""
         flat = x.astype(jnp.float32).reshape(-1)
-        k = min(flat.size, max(1, int(math.ceil(self.frac * flat.size))))
-        _, idx = jax.lax.top_k(jnp.abs(flat), k)
+        _, idx = jax.lax.top_k(jnp.abs(flat), self._k(flat.size))
         return (flat[idx], idx.astype(jnp.int32)), x.shape
 
     def decode(self, wire, meta, dtype=jnp.float32) -> jnp.ndarray:
         vals, idx = wire
-        n = 1
-        for s in meta:
-            n *= int(s)
-        return jnp.zeros((n,), jnp.float32).at[idx].set(vals) \
-            .reshape(meta).astype(dtype)
+        return jnp.zeros((math.prod(meta),), jnp.float32).at[idx] \
+            .set(vals).reshape(meta).astype(dtype)
 
-    def encode_tree(self, tree) -> Tuple[List[Tuple[Any, Any]], int]:
-        """Stateful whole-tree encode: adds the carried residual before
-        selection and advances it — exactly ``roundtrip``'s error
-        feedback, but the dropped mass is the with-residual leaf with
-        its kept entries zeroed (top-k indices are distinct), so no
-        densified decode is ever built."""
+    def leaf_bytes(self, size: int, itemsize: int = 4) -> int:
+        return 8 * self._k(size)
+
+    def encode_tree(self, tree) -> Tuple[EncTree, int]:
+        """Error feedback: adds the carried residual before selection,
+        then carries what was dropped — the with-residual leaf with its
+        kept entries zeroed (top-k indices are distinct), so no densified
+        decode is ever built."""
         if self.error_feedback and self._residual is not None:
             tree = jax.tree.map(lambda l, r: l + r.astype(l.dtype),
                                 tree, self._residual)
-        enc: List[Tuple[Any, Any]] = []
-        res_leaves = []
-        kept_entries = 0
-        for l in jax.tree.leaves(tree):
-            flat = l.astype(jnp.float32).reshape(-1)
-            k = min(flat.size, max(1, int(math.ceil(self.frac * flat.size))))
-            kept_entries += k
-            _, idx = jax.lax.top_k(jnp.abs(flat), k)
-            idx = idx.astype(jnp.int32)
-            enc.append(((flat[idx], idx), l.shape))
-            res_leaves.append(flat.at[idx].set(0.0).reshape(l.shape))
+        enc, nbytes = super().encode_tree(tree)
         if self.error_feedback:
-            self._residual = jax.tree.unflatten(jax.tree.structure(tree),
-                                                res_leaves)
-        return enc, int(kept_entries * 8)
+            self._residual = jax.tree.unflatten(
+                jax.tree.structure(tree),
+                [l.astype(jnp.float32).reshape(-1).at[idx].set(0.0)
+                 .reshape(l.shape)
+                 for l, ((_, idx), _) in zip(jax.tree.leaves(tree), enc)])
+        return enc, nbytes
 
 
 def make_codec(name: str, *, topk_frac: float = 0.01,

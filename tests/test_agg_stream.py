@@ -27,10 +27,11 @@ import pytest
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
-from repro.fed.aggregate import (StreamingAggregator, batched_reduce,
-                                 codec_rel_error, decode_enc)
+from repro.core.fedavg import fedavg
+from repro.fed.aggregate import (StreamingAggregator, Uplink, batched_reduce,
+                                 codec_rel_error, make_server_reduce)
 from repro.fed.programs import fedavg_stacked, stack_trees
-from repro.fed.transport import make_codec
+from repro.fed.transport import apply_delta, delta_tree, make_codec
 from repro.kernels.agg_fuse import (dequant_acc_flat, dequant_acc_ref,
                                     dequant_reduce_flat, dequant_reduce_ref,
                                     scatter_acc_flat, scatter_acc_ref)
@@ -124,14 +125,13 @@ def test_streaming_fold_matches_decode_then_fedavg(codec_name, weighted,
     for i, d in enumerate(deltas):
         codec = make_codec(codec_name, topk_frac=0.25, error_feedback=False)
         encs.append(codec.encode_tree(d)[0])
-    agg = StreamingAggregator(codec_name, use_kernel=use_kernel,
-                              interpret=True)
+    agg = StreamingAggregator(codec, use_kernel=use_kernel, interpret=True)
     agg.init(deltas[0])
     for enc, w in zip(encs, weights):
         agg.fold(enc, w)
     got = agg.finalize()
     # reference: decode every wire, stack, weighted fedavg
-    decoded = [decode_enc(codec_name, enc, deltas[0]) for enc in encs]
+    decoded = [codec.decode_tree(enc, deltas[0]) for enc in encs]
     want = fedavg_stacked(stack_trees(decoded), weights)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -146,12 +146,12 @@ def test_batched_reduce_matches_streaming(codec_name):
     for d in deltas:
         codec = make_codec(codec_name, topk_frac=0.25, error_feedback=False)
         encs.append(codec.encode_tree(d)[0])
-    agg = StreamingAggregator(codec_name, interpret=True)
+    agg = StreamingAggregator(codec, interpret=True)
     agg.init(deltas[0])
     for enc, w in zip(encs, weights):
         agg.fold(enc, w)
     got_s = agg.finalize()
-    got_b = batched_reduce(codec_name, encs, weights, deltas[0],
+    got_b = batched_reduce(codec, encs, weights, deltas[0],
                            use_kernel=False, interpret=True)
     for a, b in zip(jax.tree.leaves(got_s), jax.tree.leaves(got_b)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -160,19 +160,89 @@ def test_batched_reduce_matches_streaming(codec_name):
 
 def test_fold_reports_codec_error_matching_densified():
     """The in-fold rel_error (computed without densifying top-k wires)
-    equals the decode-and-compare definition ``_codec_roundtrip`` uses."""
+    equals the decode-and-compare definition the decode reduce uses."""
     d = _delta_tree(3)
     for codec_name in ("fp16", "int8", "topk"):
         codec = make_codec(codec_name, topk_frac=0.25, error_feedback=False)
         enc, _ = codec.encode_tree(d)
-        err = codec_rel_error(codec_name, enc, d)
-        dec = decode_enc(codec_name, enc, d)
+        err = codec_rel_error(codec, enc, d)
+        dec = codec.decode_tree(enc, d)
         df = jnp.concatenate([jnp.ravel(l) for l in jax.tree.leaves(d)])
         cf = jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
                               for l in jax.tree.leaves(dec)])
         want = float(jnp.linalg.norm(cf - df)
                      / jnp.maximum(jnp.linalg.norm(df), 1e-12))
         assert err == pytest.approx(want, rel=1e-4, abs=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the server reduce: every mode over three encoded uplinks
+# ---------------------------------------------------------------------------
+
+def _ref_decode(codec_name, x, frac):
+    """One leaf's wire decoded, in plain jnp per codec (the reference)."""
+    if codec_name == "none":
+        return x
+    if codec_name == "fp16":
+        return x.astype(jnp.float16).astype(jnp.float32)
+    if codec_name == "int8":
+        amax = jnp.max(jnp.abs(x))
+        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+        return jnp.clip(jnp.round(x / scale), -127, 127) * scale
+    flat = x.reshape(-1)
+    k = min(flat.size, max(1, int(np.ceil(frac * flat.size))))
+    _, idx = jax.lax.top_k(jnp.abs(flat), k)
+    return (flat * jnp.zeros_like(flat).at[idx].set(1.0)).reshape(x.shape)
+
+
+@pytest.mark.parametrize("codec_name", ["none", "fp16", "int8", "topk"])
+@pytest.mark.parametrize("mode", ["decode", "stream", "batched"])
+def test_server_reduce_matches_fedavg_of_decoded(mode, codec_name):
+    """The factory's reduce over three uplinks gives the weighted FedAvg
+    of the decoded, rebased trees and each client's codec error; staged
+    and landed (the async path) it gives each rebased tree."""
+    base = _delta_tree(20)
+    weights = [1.0, 2.5, 0.5]
+    reduce = make_server_reduce(mode, interpret=True)
+    inflight = make_server_reduce(mode, interpret=True)
+    want_trees, want_errs, errs = [], [], []
+    for i, w in enumerate(weights):
+        params = jax.tree.map(jnp.add, base, _delta_tree(21 + i))
+        codec = make_codec(codec_name, topk_frac=0.25, error_feedback=False)
+        if codec.encodes_delta:
+            delta = delta_tree(params, base)
+            up = Uplink(codec, *codec.encode_tree(delta), base, True)
+            dec = jax.tree.map(lambda l: _ref_decode(codec_name, l, 0.25),
+                               delta)
+            want_trees.append(apply_delta(base, dec))
+            num = sum(float(np.sum((np.asarray(a, np.float64)
+                                    - np.asarray(b, np.float64)) ** 2))
+                      for a, b in zip(jax.tree.leaves(dec),
+                                      jax.tree.leaves(delta)))
+            den = sum(float(np.sum(np.asarray(b, np.float64) ** 2))
+                      for b in jax.tree.leaves(delta))
+            want_errs.append(np.sqrt(num) / np.sqrt(den))
+        else:
+            delta = None
+            up = Uplink(codec, *codec.encode_tree(params), base, False)
+            want_trees.append(params)
+            want_errs.append(0.0)
+        errs.append(reduce.error(up, delta))
+        reduce.add(up, w)
+        landed = inflight.land(inflight.stage(up))
+        for a, b in zip(jax.tree.leaves(landed),
+                        jax.tree.leaves(want_trees[-1])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got = reduce.result()
+    want = fedavg(want_trees, weights)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if mode == "decode":
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    assert errs == pytest.approx(want_errs, rel=1e-4, abs=1e-6)
+    assert reduce.peak_live_trees == (3 if mode == "decode" else 1)
+    assert reduce.result() is None          # the next reduce starts empty
 
 
 # ---------------------------------------------------------------------------

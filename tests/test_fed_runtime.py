@@ -21,7 +21,7 @@ from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
 from repro.fed.events import (ARRIVE, FINISH, BernoulliAvailability,
                               EventQueue)
-from repro.fed.policies import ClientUpdate, FedAsync, FedBuff, SyncFedAvg
+from repro.fed.policies import ClientUpdate, FedAsync, FedBuff
 from repro.fed.programs import (fedavg_stacked, sequential_d_rounds,
                                 stack_trees, unstack_tree)
 from repro.fed.transport import (FP16Codec, IdentityCodec, Int8Codec,

@@ -308,6 +308,22 @@ def test_fused_stage_sigma_zero_is_deterministic():
 # codec buffer entry points (fed/transport)
 # ---------------------------------------------------------------------------
 
+def _codec_ref(codec, x, frac):
+    """Each codec's quantize-dequantize in plain jnp, as its own formula."""
+    if codec == "fp16":
+        return x.astype(jnp.float16).astype(x.dtype)
+    if codec == "int8":
+        amax = jnp.max(jnp.abs(x))
+        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+        return (jnp.clip(jnp.round(x / scale), -127, 127) * scale
+                ).astype(x.dtype)
+    flat = x.reshape(-1)
+    k = min(flat.size, max(1, int(np.ceil(frac * flat.size))))
+    _, idx = jax.lax.top_k(jnp.abs(flat), k)
+    mask = jnp.zeros_like(flat).at[idx].set(1.0)
+    return (flat * mask).reshape(x.shape).astype(x.dtype)
+
+
 @pytest.mark.parametrize("codec", ["fp16", "int8", "topk"])
 def test_codec_encode_decode_matches_roundtrip(codec):
     from repro.fed.transport import make_codec
@@ -315,8 +331,10 @@ def test_codec_encode_decode_matches_roundtrip(codec):
     x = jax.random.normal(jax.random.PRNGKey(5), (17, 9), jnp.float32) * 4.0
     wire, meta = c.encode(x)
     dec = c.decode(wire, meta, x.dtype)
-    ref, _ = c.roundtrip(x)
+    rt, _ = c.roundtrip(x)
+    ref = _codec_ref(codec, x, 0.1)
     np.testing.assert_array_equal(np.asarray(dec), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(rt), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------

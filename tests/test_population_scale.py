@@ -27,10 +27,12 @@ from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 from repro.configs.registry import get_config
 from repro.core.gan import FSLGANTrainer
 from repro.data import partition_dirichlet, synthetic_mnist
+from repro.fed.aggregate import Uplink, make_server_reduce
 from repro.fed.hierarchy import (CohortReduction, HierarchicalAggregator,
                                  assign_cohorts)
 from repro.fed.programs import RoundExecutor, fedavg_stacked, stack_trees
 from repro.fed.roster import Roster
+from repro.fed.transport import IdentityCodec
 from repro.launch.mesh import make_client_mesh, make_host_mesh, mesh_chips
 from repro.sharding.specs import (client_axis_rules, logical_spec,
                                   stacked_shardings, tree_shardings, Lg)
@@ -122,12 +124,14 @@ def test_assign_cohorts_contiguous_and_balanced():
 
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_two_tier_reduce_matches_flat_fedavg(use_kernel):
-    ups = {f"c{i}": (_tree(float(i), seed=i), 1.0 + i) for i in range(6)}
-    h = HierarchicalAggregator(2, use_kernel=use_kernel, interpret=True)
-    reds = h.reduce_all(ups)
+    ups = {f"c{i}": (Uplink.plain(IdentityCodec(), _tree(float(i), seed=i)),
+                     1.0 + i) for i in range(6)}
+    h = HierarchicalAggregator(2)
+    reds = h.reduce_all(ups, make_server_reduce(
+        "decode", edge=True, use_kernel=use_kernel, interpret=True))
     assert [r.cohort for r in reds] == [0, 1]
     assert sum(len(r.members) for r in reds) == 6
-    flat = fedavg_stacked(stack_trees([u[0] for u in ups.values()]),
+    flat = fedavg_stacked(stack_trees([u[0].base for u in ups.values()]),
                           [u[1] for u in ups.values()])
     two = fedavg_stacked(stack_trees([r.aggregate for r in reds]),
                          [r.weight for r in reds])
